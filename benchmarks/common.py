@@ -8,10 +8,12 @@ section into ``BENCH_sweep.json`` (one file, one section per benchmark, each
 tagged with ``device_count``) so the perf trajectory across commits
 distinguishes 1- from multi-device runs.
 
-NOTE: importing this module does NOT initialise the jax backend, so
-:func:`force_host_devices` can still grow the fake-CPU device count — but it
-must be called before any ``jax.devices()`` / first computation, i.e. before
-importing ``repro.*`` modules (some probe the platform at import).
+NOTE: importing this module (or any ``repro.*`` module) does NOT
+initialise the jax backend, so :func:`force_host_devices` can still grow the
+fake-CPU device count — but it must be called before any ``jax.devices()``
+/ first computation. Each benchmark's ``__main__`` block turns on the
+persistent compilation cache
+(:func:`repro.launch.compile_cache.enable_compile_cache`).
 """
 from __future__ import annotations
 

@@ -41,4 +41,6 @@ def main(n_events: int = N_EVENTS) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -52,4 +52,6 @@ def main(n_day1: int = 32_768, n_day2: int = 49_152,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

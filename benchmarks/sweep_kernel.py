@@ -107,7 +107,8 @@ def main(n_events: int = 2048, n_campaigns: int = 32,
     from repro.core import segments as seg_lib
     from repro.core.parallel import lane_predict
     from repro.data import make_synthetic_env
-    from repro.kernels.auction_resolve import ON_TPU, sweep_resolve
+    from repro.kernels import on_tpu
+    from repro.kernels.auction_resolve import sweep_resolve
 
     env = make_synthetic_env(jax.random.PRNGKey(0), n_events=n_events,
                              n_campaigns=n_campaigns, emb_dim=8)
@@ -128,10 +129,11 @@ def main(n_events: int = 2048, n_campaigns: int = 32,
     lane_pred = functools.partial(lane_predict, n_events=n_events)
 
     def _reduce(winners, prices, b, s_hat, act, n_hat):
-        rates = jax.vmap(
-            lambda w, p, nh: seg_lib.rate_from_events(w, p, n_campaigns, nh)
+        sums = jax.vmap(
+            lambda w, p, nh: seg_lib.block_from_events(w, p, n_campaigns, nh,
+                                                       n_events)
         )(winners, prices, n_hat)
-        c_next, no_cap, n_next = jax.vmap(lane_pred)(rates, b, s_hat, act,
+        c_next, no_cap, n_next = jax.vmap(lane_pred)(sums, b, s_hat, act,
                                                      n_hat)
         blk = jax.vmap(
             lambda w, p, lo, hi: seg_lib.block_from_events(
@@ -451,7 +453,7 @@ def main(n_events: int = 2048, n_campaigns: int = 32,
     if records:
         update_bench_json(out, "sweep_kernel", bench_report(
             records, n_events=n_events, n_campaigns=n_campaigns,
-            block_t=block_t, pallas_interpret=not ON_TPU))
+            block_t=block_t, pallas_interpret=not on_tpu()))
 
     # CI gate: the fused round oracle must beat (or at worst match) the
     # unfused resolve+reduce dispatch pair at the largest S on CPU — if
@@ -460,7 +462,7 @@ def main(n_events: int = 2048, n_campaigns: int = 32,
     # survive even the median-of-15) from failing the build; a genuine
     # fusion regression shows up far past it (quiet-machine wins measured
     # at 1.5–2.9×).
-    if not ON_TPU and round_gate:
+    if not on_tpu() and round_gate:
         s_gate = max(round_gate)
         us_fused, us_unfused = round_gate[s_gate]
         if us_fused > 1.15 * us_unfused:
@@ -485,6 +487,8 @@ def main(n_events: int = 2048, n_campaigns: int = 32,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = sweep_argparser(__doc__.splitlines()[0], n_events=2048,
                          n_campaigns=32, s_values=(1, 8, 32), block_t=256,
                          out="BENCH_sweep.json")

@@ -119,6 +119,8 @@ def main(n_events: int = 16_384, n_campaigns: int = 16,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = sweep_argparser(__doc__.splitlines()[0], n_events=16_384,
                          n_campaigns=16, out="BENCH_sweep.json",
                          device_count=True)

@@ -105,4 +105,6 @@ def main(n_events: int = 8_192, n_campaigns: int = 16,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -71,4 +71,6 @@ def main(n_events: int = 32_768, n_campaigns: int = 32) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -1,20 +1,16 @@
-"""Cross-version jax compatibility helpers.
+"""The one seam between this repo and the installed jax (0.9).
 
-The repo targets the jax that ships in the image (0.4.x today) while using
-the modern spellings where available:
+Every call whose spelling has moved between jax releases goes through here,
+so the next move is a change in one place:
 
-* ``shard_map`` — top-level ``jax.shard_map(..., check_vma=...)`` appeared in
-  jax 0.6; older releases carry it as ``jax.experimental.shard_map.shard_map``
-  with the kwarg named ``check_rep``. We always disable the replication check
-  (our kernels return replicated (C,)-vectors from explicit psums, which the
-  checker cannot always prove).
-* ``AxisType`` — re-exported from :mod:`repro.launch.mesh`'s shim via
-  ``make_mesh`` there; nothing needed here.
+* ``shard_map`` — ``jax.shard_map`` with the replication (VMA) check off:
+  our kernels return replicated (C,)-vectors from explicit psums, which the
+  checker cannot always prove;
+* ``axis_size`` — the size of a mapped mesh axis;
 * multi-process helpers — ``jax.distributed`` initialisation (CPU runs need
-  the gloo collectives implementation selected before init on 0.4.x/0.5.x)
-  and the host-local <-> global array conversions the ``multihost``
-  placement uses (:mod:`jax.experimental.multihost_utils` today; kept
-  behind one seam so a future jax can swap the spelling in one place).
+  the gloo collectives implementation selected before init) and the
+  host-local <-> global array conversions the ``multihost`` placement uses
+  (:mod:`jax.experimental.multihost_utils`).
 """
 from __future__ import annotations
 
@@ -22,36 +18,15 @@ import functools
 
 import jax
 
-try:  # jax >= 0.6
-    _shard_map_impl = jax.shard_map
-    _CHECK_KWARG = "check_vma"
-except AttributeError:  # jax 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _CHECK_KWARG = "check_rep"
-
 
 def axis_size(axis_name):
-    """Size of a mapped mesh axis, from inside shard_map/pmap.
-
-    ``jax.lax.axis_size`` is a 0.5+ addition; a psum of ones is the portable
-    spelling (constant-folded by XLA, so there is no runtime collective).
-    """
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:
-        return jax.lax.psum(1, axis_name)
+    """Size of a mapped mesh axis, from inside shard_map/pmap."""
+    return jax.lax.axis_size(axis_name)
 
 
 def compiled_cost_analysis(compiled):
-    """``Compiled.cost_analysis()`` as a flat dict on every jax version.
-
-    jax 0.4.x returns a one-element list of dicts (per executable);
-    newer jax returns the dict directly.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0]
-    return cost
+    """``Compiled.cost_analysis()`` as a flat dict."""
+    return compiled.cost_analysis()
 
 
 def process_count() -> int:
@@ -71,8 +46,7 @@ def distributed_initialize(coordinator_address=None, num_processes=None,
     selected first.
 
     On CPU the cross-process collectives implementation must be chosen
-    *before* the backend initialises (jaxlib ships gloo; the config key is
-    ``jax_cpu_collectives_implementation`` on 0.4.x–0.5.x). The knob is set
+    *before* the backend initialises (jaxlib ships gloo). The knob is set
     unconditionally — probing the platform first (``jax.default_backend()``)
     would itself initialise the backend, which ``jax.distributed`` forbids;
     on TPU/GPU the runtime ignores it, so setting it is harmless.  All
@@ -80,11 +54,8 @@ def distributed_initialize(coordinator_address=None, num_processes=None,
     to its cluster auto-detection (the usual TPU-pod path).
     """
     if cpu_collectives:
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              cpu_collectives)
-        except (AttributeError, ValueError):  # pragma: no cover - old jax
-            pass
+        jax.config.update("jax_cpu_collectives_implementation",
+                          cpu_collectives)
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)
@@ -110,7 +81,7 @@ def global_to_host_local(arr, mesh, pspec):
 
 
 def shard_map(fun=None, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with the replication/VMA check disabled, on any jax.
+    """``jax.shard_map`` with the replication/VMA check disabled.
 
     Usable as a decorator factory exactly like the modern API:
     ``@functools.partial(shard_map, mesh=mesh, in_specs=..., out_specs=...)``.
@@ -118,5 +89,5 @@ def shard_map(fun=None, *, mesh, in_specs, out_specs):
     if fun is None:
         return functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
                                  out_specs=out_specs)
-    return _shard_map_impl(fun, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **{_CHECK_KWARG: False})
+    return jax.shard_map(fun, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -83,6 +83,7 @@ from repro.core import auction
 from repro.core import crn
 from repro.core import segments as seg_lib
 from repro.core.types import AuctionRule, ScenarioOverlay, never_capped
+from repro import kernels
 from repro.kernels.auction_resolve import ops as resolve_ops
 from repro.launch.mesh import SweepMeshSpec
 
@@ -113,7 +114,7 @@ def pick_resolve(resolve: str, on_tpu: Optional[bool] = None) -> str:
     correctness harness, not a production path (regression-tested in
     tests/test_scenario_sweep.py).
     """
-    on_tpu = resolve_ops.ON_TPU if on_tpu is None else on_tpu
+    on_tpu = kernels.on_tpu() if on_tpu is None else on_tpu
     if resolve == "auto":
         return "fused" if on_tpu else "jnp"
     if resolve not in RESOLVE_BACKENDS:
@@ -129,7 +130,7 @@ def fused_runs_kernel(interpret: Optional[bool]) -> bool:
     (kernel tests); otherwise the fused round runs its jnp oracle
     composition (the exact ``lane_round`` stages) — never an *implicit*
     interpret-mode kernel."""
-    return resolve_ops.ON_TPU or interpret is True
+    return kernels.on_tpu() or interpret is True
 
 
 def check_sim_driver(driver: str) -> str:
@@ -690,22 +691,27 @@ def _local_overlay(overlay: Optional[ScenarioOverlay]):
     return dataclasses.replace(overlay, key=None)
 
 
-# One-launch fused-round VMEM budget: the kernel keeps TWO (S, G, C_pad)
-# float32 partials blocks + a (block_t, C_pad) values tile + ~6 (S, C_pad)
-# scenario-state blocks resident (docs/ALGORITHMS.md budget table: S=32
-# fits at C=1024, S=64 does not). Conservative against a 16 MiB VMEM so
-# padding/overheads don't push a "fits" plan over on real hardware.
-ONE_LAUNCH_VMEM_BYTES = 12 << 20
+# One-launch fused-round VMEM budget: the TPU v5e compiler's default scoped
+# VMEM limit, which is what refuses an over-full kernel.
+ONE_LAUNCH_VMEM_BYTES = 16 << 20
 
 
 def round_fused_bytes(n_scenarios: int, n_campaigns: int,
                       block_t: int = 256) -> int:
-    """Resident float32 bytes the one-launch ``round_fused`` kernel keeps in
-    VMEM: two (S, G, C_pad) partials blocks, one (block_t, C_pad) values
-    tile, ~6 (S, C_pad) scenario-state blocks."""
+    """Float32 bytes of VMEM the one-launch ``round_fused`` kernel needs:
+    two (S, G, C_pad) partials blocks, four double-buffered (S, C_pad)
+    scenario-state blocks, four (block_t, C_pad) tiles (the double-buffered
+    values tile and the resolve's temporaries) and four (block_t, 128)
+    lane-padded per-row columns. Fitted to the v5e compiler (described
+    topology) so that every size it admits compiles: at C=1024,
+    block_t=256 it admits S <= 40 where the compiler takes S <= 52; where
+    tile temporaries dominate (block_t=1024, or C >= 2048) it stays under
+    the compiler's limit too (docs/ALGORITHMS.md; tests/test_tpu_compile.py
+    compiles the largest S it admits)."""
     c_pad = -(-n_campaigns // 128) * 128
     return (2 * n_scenarios * seg_lib.REDUCE_BLOCKS * c_pad
-            + block_t * c_pad + 6 * n_scenarios * c_pad) * 4
+            + 8 * n_scenarios * c_pad + 4 * block_t * c_pad
+            + 4 * block_t * 128) * 4
 
 
 def round_fused_fits(n_scenarios: int, n_campaigns: int,
@@ -773,15 +779,22 @@ def global_event_offset(event_axes, local_n: int) -> jax.Array:
 # Per-lane scalar logic (the bit-for-bit contract between ALL placements)
 # ---------------------------------------------------------------------------
 
-def lane_predict(rates, b, s_hat, active, n_hat, *, n_events):
-    """Scalar half 1 of an Algorithm-2 round: from the current remaining-rate
-    estimate, predict which campaign caps out next and where its block ends.
+def lane_predict(sums, b, s_hat, active, n_hat, *, n_events):
+    """Scalar half 1 of an Algorithm-2 round: from the spend of the
+    remaining events ``[n_hat, N)`` (``sums``, the canonical block sum of
+    the rate partials), predict which campaign caps out next and where its
+    block ends.
 
     Returns ``(c_next, no_cap, n_next)``; pure per-lane O(C) arithmetic, no
     event-log access — every placement runs it verbatim between its two
-    reductions.
+    reductions, and the fused round kernel repeats it op for op.
     """
-    ttl = jnp.where(active & (rates > 0), (b - s_hat) / rates,
+    denom = jnp.maximum(n_events - n_hat, 1).astype(sums.dtype)
+    rates = sums / denom                      # the remaining-rate estimate
+    # time to live (b - s_hat) / rates, spelled as XLA simplifies
+    # A / (B / C) = (A * C) / B: written so, every program — XLA's and the
+    # fused kernel's, which compiles without that rewrite — rounds alike
+    ttl = jnp.where(active & (rates > 0), (b - s_hat) * denom / sums,
                     jnp.float32(jnp.inf))
     ttl = jnp.where(ttl < 0, jnp.float32(0.0), ttl)  # past budget -> retire
     c_next = jnp.argmin(ttl).astype(jnp.int32)
@@ -822,8 +835,9 @@ def lane_round(winners, prices, b, s_hat, active, cap, n_hat, rnd, retired,
     produced (one kernel launch / per-chunk scans / per-shard psums) — the
     tensors themselves, and hence every downstream bit, are identical.
     """
-    rates = seg_lib.rate_from_events(winners, prices, n_campaigns, n_hat)
-    c_next, no_cap, n_next = lane_predict(rates, b, s_hat, active, n_hat,
+    sums = seg_lib.block_from_events(winners, prices, n_campaigns, n_hat,
+                                     n_events)
+    c_next, no_cap, n_next = lane_predict(sums, b, s_hat, active, n_hat,
                                           n_events=n_events)
     blk = seg_lib.block_from_events(winners, prices, n_campaigns, n_hat,
                                     n_next)
@@ -937,11 +951,14 @@ def _make_round_body(plan: SweepPlan, resolve: str, *, values_local,
         return jax.vmap(one)(winners, prices, lo, hi)
 
     def kernel_partials(v, active, keep, lo, hi, offset):
-        """One fused resolve+reduce kernel pass over ``v`` (NOT psum'd)."""
+        """One fused resolve+reduce kernel pass over ``v`` (NOT psum'd).
+        Shards and chunks start on canonical block boundaries; only a
+        resumable fold's rows start ``resume_offset % block`` into one."""
         return resolve_ops.sweep_partials(
             v, rules_local.multipliers, active, rules_local.reserve,
             lo, hi, keep, offset, n_events_global=n_events,
-            reduce_blocks=seg_lib.REDUCE_BLOCKS, second_price=second,
+            reduce_blocks=seg_lib.REDUCE_BLOCKS,
+            offset_in_block=resume_offset % block, second_price=second,
             skip_retired=plan.skip_retired, block_t=plan.block_t,
             interpret=use_interpret)
 
@@ -979,11 +996,6 @@ def _make_round_body(plan: SweepPlan, resolve: str, *, values_local,
                          jnp.arange(n_chunks, dtype=jnp.int32)))
         return psum(parts)
 
-    def rate_of(parts_s, nh):
-        sums = parts_s.sum(axis=0)
-        denom = jnp.maximum(n_events - nh, 1).astype(sums.dtype)
-        return sums / denom
-
     def round_body(core, keep):
         s_hat, active, cap, n_hat, rnd, retired, bnds = core
         # static live windows AND into the mask every resolve sees;
@@ -1000,7 +1012,7 @@ def _make_round_body(plan: SweepPlan, resolve: str, *, values_local,
                 reduce_blocks=seg_lib.REDUCE_BLOCKS, second_price=second,
                 skip_retired=plan.skip_retired, block_t=plan.block_t,
                 interpret=use_interpret)
-            blk = block_parts.sum(axis=1)
+            blk = seg_lib.sum_blocks(block_parts)
         else:
             hi_all = jnp.full_like(n_hat, n_events)
             if two_pass:
@@ -1010,15 +1022,14 @@ def _make_round_body(plan: SweepPlan, resolve: str, *, values_local,
                                               z_local, u_local)
                 rate_parts = psum(weighted_partials(winners, prices, n_hat,
                                                     hi_all, offset_fn()))
-            rates = jax.vmap(rate_of)(rate_parts, n_hat)
-            c_next, no_cap, n_next = jax.vmap(lane_pred)(rates, b, s_hat,
-                                                         active, n_hat)
+            c_next, no_cap, n_next = jax.vmap(lane_pred)(
+                seg_lib.sum_blocks(rate_parts), b, s_hat, active, n_hat)
             if two_pass:
                 block_parts = window_partials(act, keep, n_hat, n_next)
             else:
                 block_parts = psum(weighted_partials(winners, prices, n_hat,
                                                      n_next, offset_fn()))
-            blk = block_parts.sum(axis=1)
+            blk = seg_lib.sum_blocks(block_parts)
         return jax.vmap(lane_comm)(blk, c_next, no_cap, n_next, s_hat,
                                    active, cap, rnd, retired, bnds)
 
@@ -1141,8 +1152,7 @@ def _sweep_batched(values, budgets, rules, overlay, plan: SweepPlan):
     check_chunks(plan.chunks, n_events=n_events, local_n=n_events)
     check_scenario_chunks(plan.scenario_chunks, n_scenarios=n_scenarios,
                           local_s=n_scenarios)
-    use_interpret = (plan.interpret if plan.interpret is not None
-                     else not resolve_ops.ON_TPU)
+    use_interpret = kernels.use_interpret(plan.interpret)
     noise = _overlay_noise(overlay, n_events, n_campaigns)
     core = _run_lanes(
         plan, resolve, values_local=values, mult_local=rules.multipliers,
@@ -1171,8 +1181,7 @@ def _sweep_sharded(values, budgets, rules, overlay, plan: SweepPlan):
     check_scenario_chunks(
         plan.scenario_chunks, n_scenarios=budgets.shape[0],
         local_s=budgets.shape[0] // spec.scenario_device_count)
-    use_interpret = (plan.interpret if plan.interpret is not None
-                     else not resolve_ops.ON_TPU)
+    use_interpret = kernels.use_interpret(plan.interpret)
     axes = tuple(spec.event_axes)
     sc = spec.scenario_axis
 
@@ -1217,18 +1226,16 @@ def _sweep_sharded(values, budgets, rules, overlay, plan: SweepPlan):
 # Host-streamed placement: the log lives in host RAM, chunks flow H2D
 # ---------------------------------------------------------------------------
 
-def _hs_use_interpret(plan: SweepPlan) -> bool:
-    return (plan.interpret if plan.interpret is not None
-            else not resolve_ops.ON_TPU)
-
-
 @functools.partial(jax.jit, static_argnames=("plan", "resolve", "kind",
-                                             "n_events", "n_campaigns"))
+                                             "n_events", "n_campaigns",
+                                             "offset_in_block"))
 def _hs_chunk_partials(acc, v_k, mult, res, act, keep, lo, hi, off_k, *,
                        plan: SweepPlan, resolve: str, kind: str,
-                       n_events: int, n_campaigns: int):
-    """One pipeline step: fold chunk ``v_k`` (global rows from ``off_k``)
-    into the (S, G, C) canonical-partials accumulator.
+                       n_events: int, n_campaigns: int,
+                       offset_in_block: int):
+    """One pipeline step: fold chunk ``v_k`` (global rows from ``off_k``,
+    ``off_k % block == offset_in_block``) into the (S, G, C)
+    canonical-partials accumulator.
 
     This is the IDENTICAL per-chunk program as the device-resident chunk
     scan's step (``window_partials`` in :func:`_make_round_body`) — same
@@ -1238,13 +1245,14 @@ def _hs_chunk_partials(acc, v_k, mult, res, act, keep, lo, hi, off_k, *,
     traced scalar, so every chunk reuses one compiled program.
     """
     second = kind == "second_price"
-    use_interpret = _hs_use_interpret(plan)
+    use_interpret = kernels.use_interpret(plan.interpret)
     if resolve == "fused" and fused_runs_kernel(plan.interpret):
         parts_k = resolve_ops.sweep_partials(
             v_k, mult, act, res, lo, hi, keep, off_k,
             n_events_global=n_events, reduce_blocks=seg_lib.REDUCE_BLOCKS,
-            second_price=second, skip_retired=plan.skip_retired,
-            block_t=plan.block_t, interpret=use_interpret)
+            offset_in_block=offset_in_block, second_price=second,
+            skip_retired=plan.skip_retired, block_t=plan.block_t,
+            interpret=use_interpret)
     else:
         if resolve == "pallas":
             winners, prices, _ = resolve_ops.sweep_resolve(
@@ -1275,14 +1283,8 @@ def _hs_chunk_partials(acc, v_k, mult, res, act, keep, lo, hi, off_k, *,
 @functools.partial(jax.jit, static_argnames=("n_events",))
 def _hs_predict(rate_parts, b, s_hat, active, n_hat, *, n_events: int):
     """Scalar half 1 between the two streamed passes (per-lane, O(S·C))."""
-    def rate_of(parts_s, nh):
-        sums = parts_s.sum(axis=0)
-        denom = jnp.maximum(n_events - nh, 1).astype(sums.dtype)
-        return sums / denom
-
-    rates = jax.vmap(rate_of)(rate_parts, n_hat)
     return jax.vmap(functools.partial(lane_predict, n_events=n_events))(
-        rates, b, s_hat, active, n_hat)
+        seg_lib.sum_blocks(rate_parts), b, s_hat, active, n_hat)
 
 
 @functools.partial(jax.jit, static_argnames=("n_events",))
@@ -1292,7 +1294,7 @@ def _hs_commit(core, keep, block_parts, c_next, no_cap, n_next, *,
     the block partials into the carried core exactly as ``_run_loop``'s
     body merges a round, and report which lanes stay alive."""
     s_hat, active, cap, n_hat, rnd, retired, bnds = core
-    blk = block_parts.sum(axis=1)
+    blk = seg_lib.sum_blocks(block_parts)
     lane_comm = functools.partial(
         lane_commit, sentinel=jnp.int32(never_capped(n_events)))
     new = jax.vmap(lane_comm)(blk, c_next, no_cap, n_next, s_hat, active,
@@ -1347,8 +1349,12 @@ def _sweep_hoststream(stream: HostStream, budgets, rules, plan: SweepPlan,
     b = jnp.asarray(budgets).astype(jnp.float32)
     mult = jnp.asarray(rules.multipliers)
     res = jnp.asarray(rules.reserve, jnp.float32)
+    # every chunk starts n_seen (mod the canonical block) into a block:
+    # chunks hold whole blocks (check_chunks above)
     statics = dict(plan=plan, resolve=resolve, kind=rules.kind,
-                   n_events=n_events, n_campaigns=n_campaigns)
+                   n_events=n_events, n_campaigns=n_campaigns,
+                   offset_in_block=n_seen % seg_lib.reduce_block_size(
+                       n_events))
 
     if carry is None:
         core = (
@@ -1611,8 +1617,7 @@ def _resume_batched(values_new, budgets, rules, s_hat0, active0, cap0,
     n_new, n_campaigns = values_new.shape
     n_total = n_seen + n_new
     check_chunks(plan.chunks, n_events=n_total, local_n=n_new)
-    use_interpret = (plan.interpret if plan.interpret is not None
-                     else not resolve_ops.ON_TPU)
+    use_interpret = kernels.use_interpret(plan.interpret)
     sentinel = jnp.int32(never_capped(n_total))
     # not-yet-capped campaigns carried the previous fold's sentinel; move
     # them to the grown log's (capped campaigns keep their global index)

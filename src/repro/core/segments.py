@@ -127,6 +127,21 @@ def reduce_block_size(n_events: int) -> int:
     return -(-n_events // REDUCE_BLOCKS)
 
 
+def sum_blocks(parts: jax.Array) -> jax.Array:
+    """The canonical final reduce: ``(..., REDUCE_BLOCKS, C)`` partials ->
+    ``(..., C)``, adding the blocks' rows in block order.
+
+    Spelled as an explicit sequence of adds (not ``.sum``) so its rounding
+    is the same in every program that computes it — XLA on any backend and
+    the fused round kernel's in-kernel prediction (which repeats this order
+    row by row) — where a reduce's association order is the compiler's
+    choice."""
+    acc = parts[..., 0, :]
+    for g in range(1, parts.shape[-2]):
+        acc = acc + parts[..., g, :]
+    return acc
+
+
 def partial_spend_sums(
     winners: jax.Array, prices: jax.Array, num_campaigns: int,
     weights: jax.Array | None = None,
@@ -163,7 +178,7 @@ def rate_from_events(
     weight = (jnp.arange(n_events) >= start).astype(prices.dtype)
     parts = partial_spend_sums(winners, prices, num_campaigns, weight,
                                block_size=reduce_block_size(n_events))
-    sums = parts.sum(axis=0)
+    sums = sum_blocks(parts)
     denom = jnp.maximum(n_events - start, 1).astype(sums.dtype)
     return sums / denom
 
@@ -181,7 +196,7 @@ def block_from_events(
     weight = ((idx >= lo) & (idx < hi)).astype(prices.dtype)
     parts = partial_spend_sums(winners, prices, num_campaigns, weight,
                                block_size=reduce_block_size(n_events))
-    return parts.sum(axis=0)
+    return sum_blocks(parts)
 
 
 @jax.jit

@@ -114,7 +114,7 @@ def make_sharded_kernels(mesh: Mesh, rule: AuctionRule,
     def _rate_kernel(values_local, active, lo):
         parts, n_events = _resolve_partials(values_local, active,
                                             lambda g: g >= lo)
-        sums = parts.sum(axis=0)
+        sums = seg_lib.sum_blocks(parts)
         denom = jnp.maximum(n_events - lo, 1).astype(sums.dtype)
         return sums / denom
 
@@ -124,7 +124,7 @@ def make_sharded_kernels(mesh: Mesh, rule: AuctionRule,
     def _block_kernel(values_local, active, lo, hi):
         parts, _ = _resolve_partials(values_local, active,
                                      lambda g: (g >= lo) & (g < hi))
-        return parts.sum(axis=0)
+        return seg_lib.sum_blocks(parts)
 
     rate_jit = jax.jit(_rate_kernel)
     block_jit = jax.jit(_block_kernel)

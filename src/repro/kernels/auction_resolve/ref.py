@@ -180,10 +180,11 @@ def round_fused_ref(
         reduce_blocks=reduce_blocks, second_price=second_price)
 
     # lane_predict, vectorised over lanes (same arithmetic, same order)
-    rates = rate_parts.sum(axis=1) / jnp.maximum(
-        n_events - n_hat[:, None], 1).astype(jnp.float32)
+    sums = rate_parts.sum(axis=1)
+    denom = jnp.maximum(n_events - n_hat[:, None], 1).astype(jnp.float32)
+    rates = sums / denom
     ttl = jnp.where(active & (rates > 0),
-                    (budgets.astype(jnp.float32) - s_hat) / rates,
+                    (budgets.astype(jnp.float32) - s_hat) * denom / sums,
                     jnp.float32(jnp.inf))
     ttl = jnp.where(ttl < 0, jnp.float32(0.0), ttl)
     c_next = jnp.argmin(ttl, axis=1).astype(jnp.int32)

@@ -6,9 +6,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import use_interpret
 from repro.kernels.capped_scan.capped_scan import capped_scan_pallas
-
-_ON_TPU = any(d.platform == "tpu" for d in jax.devices())
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
@@ -19,7 +18,7 @@ def capped_scan(
     reserve: jax.Array = 0.0,
     *,
     block_t: int = 512,
-    interpret: bool = not _ON_TPU,
+    interpret: bool | None = None,
 ):
     n, c = values.shape
     if multipliers is None:
@@ -33,6 +32,6 @@ def capped_scan(
     m = jnp.pad(multipliers.astype(jnp.float32), (0, pad_c))
     winners, prices, spend, cap = capped_scan_pallas(
         v, b, m, jnp.asarray(reserve, jnp.float32), block_t=block_t,
-        interpret=interpret)
+        interpret=use_interpret(interpret))
     cap = jnp.minimum(cap[:c], n + 1)          # padded-N sentinel -> n+1
     return winners[:n], prices[:n], spend[:c], cap

@@ -7,9 +7,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import use_interpret
 from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
-
-_ON_TPU = any(d.platform == "tpu" for d in jax.devices())
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
@@ -23,7 +22,7 @@ def flash_attention(
     window: Optional[int] = None,
     block_q: int = 256,
     block_k: int = 256,
-    interpret: bool = not _ON_TPU,
+    interpret: bool | None = None,
 ) -> jax.Array:
     b, s, h, dh = q.shape
     kv = k.shape[2]
@@ -41,5 +40,5 @@ def flash_attention(
     vf = v.transpose(0, 2, 1, 3).reshape(b * h, s, dh)
     o = flash_attention_pallas(qf, kf, vf, causal=causal, window=window,
                                block_q=max(bq, 1), block_k=max(bk, 1),
-                               interpret=interpret)
+                               interpret=use_interpret(interpret))
     return o.reshape(b, h, s, dh).transpose(0, 2, 1, 3)

@@ -19,19 +19,12 @@ import dataclasses
 from typing import Optional, Tuple
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: pass ``axis_types`` only where
-    the installed jax knows about it (``jax.sharding.AxisType`` appeared in
-    0.5.x; on older releases every axis is implicitly Auto)."""
-    if AxisType is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with every axis explicitly ``AxisType.Auto`` (the
+    one place this repo spells ``AxisType``)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
                          axis_types=(AxisType.Auto,) * len(axes))
 

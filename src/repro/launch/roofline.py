@@ -14,10 +14,10 @@ all-to-all / collective-permute we take the result tensor sizes and convert to
 per-device wire traffic with the standard ring formulas (x(n-1)/n, all-reduce
 x2(n-1)/n) using the replica-group size parsed from the op.
 
-Hardware parameters live in :class:`HardwareSpec`; the module-level
-``PEAK_FLOPS`` / ``HBM_BW`` / ``ICI_BW`` constants are the TPU v5e-like
-defaults (197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI) kept for
-callers that predate the dataclass.
+Hardware parameters live in :class:`HardwareSpec`, one per JAX
+``device_kind`` in :data:`HARDWARE`; the module-level ``PEAK_FLOPS`` /
+``HBM_BW`` / ``ICI_BW`` constants are the TPU v5e row, kept for callers
+that predate the table.
 """
 from __future__ import annotations
 
@@ -48,25 +48,36 @@ class HardwareSpec:
     dispatch_us: float = 3.0   # per kernel-launch overhead, microseconds
 
     @staticmethod
-    def for_backend(backend: str) -> "HardwareSpec":
-        """Best-guess spec for a jax backend name ('tpu'/'gpu'/'cpu')."""
-        key = {"tpu": "tpu-v5e", "gpu": "gpu-a100", "cpu": "cpu"}.get(
-            backend, "cpu")
-        return HARDWARE[key]
+    def for_device_kind(device_kind: str) -> "HardwareSpec":
+        """The table row for a JAX ``device_kind`` (``jax.devices()[0]
+        .device_kind``). A kind the table does not know is an error, not a
+        default: a peak assumed for the wrong chip makes every roofline
+        share and cost ranking wrong."""
+        try:
+            return HARDWARE[device_kind]
+        except KeyError:
+            raise ValueError(
+                f"no HardwareSpec for device_kind {device_kind!r}; known: "
+                f"{sorted(HARDWARE)}. Add its published peaks to "
+                "repro.launch.roofline.HARDWARE, with their source."
+            ) from None
 
 
+# Keyed by JAX's device_kind. Sources: Google Cloud TPU documentation,
+# "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of chip-to-chip
+# interconnect over 4 links: ~50 GB/s a link) and "TPU v4" (275 TFLOP/s
+# bf16, 1,228 GB/s HBM).
 HARDWARE: Dict[str, HardwareSpec] = {
-    # per task spec: 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI
-    "tpu-v5e": HardwareSpec("tpu-v5e", 197e12, 819e9, 50e9, h2d_bw=16e9),
-    "tpu-v4": HardwareSpec("tpu-v4", 275e12, 1228e9, 100e9, h2d_bw=16e9),
-    "gpu-a100": HardwareSpec("gpu-a100", 312e12, 2039e9, 300e9, h2d_bw=25e9),
-    # CPU numbers are a coarse single-socket stand-in; the tuner only needs
-    # *relative* ranking on this backend, and measurement decides the rest.
+    "TPU v5 lite": HardwareSpec("TPU v5 lite", 197e12, 819e9, 50e9,
+                                h2d_bw=16e9),
+    "TPU v4": HardwareSpec("TPU v4", 275e12, 1228e9, 100e9, h2d_bw=16e9),
+    # not a measured device: a coarse single-socket stand-in so the plan
+    # tuner can rank candidates on CPU test runs (measurement decides)
     "cpu": HardwareSpec("cpu", 0.5e12, 50e9, 50e9, h2d_bw=50e9,
                         dispatch_us=8.0),
 }
 
-V5E = HARDWARE["tpu-v5e"]
+V5E = HARDWARE["TPU v5 lite"]
 PEAK_FLOPS = V5E.peak_flops    # bf16 / chip
 HBM_BW = V5E.hbm_bw            # bytes/s / chip
 ICI_BW = V5E.ici_bw            # bytes/s / link

@@ -144,7 +144,7 @@ def autotune(values, budgets, rules, plan: ex.SweepPlan, *,
                                 n_campaigns=n_campaigns,
                                 n_scenarios=n_scenarios)
     if hw is None:
-        hw = HardwareSpec.for_backend(shape.platform)
+        hw = HardwareSpec.for_device_kind(shape.device_kind)
     ranked = space_lib.rank_candidates(plan, shape, hw)
     candidates = [c for c, _ in ranked]
     predicted = {c: p.total for c, p in ranked}

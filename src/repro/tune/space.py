@@ -50,6 +50,7 @@ class ProblemShape:
     n_scenarios: int
     platform: str = "cpu"          # jax.default_backend()
     device_count: int = 1
+    device_kind: str = "cpu"       # jax.devices()[0].device_kind
     placement: str = "batched"
     resolve: str = "jnp"           # concrete back-end (pick_resolve applied)
     source: str = "device"         # event log residency
@@ -62,7 +63,8 @@ def shape_for(plan: ex.SweepPlan, *, n_events: int, n_campaigns: int,
     return ProblemShape(
         n_events=int(n_events), n_campaigns=int(n_campaigns),
         n_scenarios=int(n_scenarios), platform=jax.default_backend(),
-        device_count=jax.device_count(), placement=plan.placement,
+        device_count=jax.device_count(),
+        device_kind=jax.devices()[0].device_kind, placement=plan.placement,
         resolve=ex.pick_resolve(plan.resolve),
         source=plan.chunks.source if plan.chunks is not None else "device")
 
@@ -302,7 +304,7 @@ def predicted_cost(cand: Candidate, plan: ex.SweepPlan,
     (skip_retired).
     """
     if hw is None:
-        hw = HardwareSpec.for_backend(shape.platform)
+        hw = HardwareSpec.for_device_kind(shape.device_kind)
     local_n, local_s = _local_counts(plan, shape)
     n, c, s = shape.n_events, shape.n_campaigns, local_s
     rounds = min(shape.n_campaigns, 64) + 1       # cap-out rounds, worst-ish
@@ -388,7 +390,7 @@ def dryrun_terms(cand: Candidate, plan: ex.SweepPlan, shape: ProblemShape,
     if shape.source == "host" or shape.placement == "multihost":
         return None
     if hw is None:
-        hw = HardwareSpec.for_backend(shape.platform)
+        hw = HardwareSpec.for_device_kind(shape.device_kind)
     concrete = cand.apply(plan)
     if concrete.placement == "device":
         b = jax.ShapeDtypeStruct((shape.n_campaigns,), jnp.float32)
@@ -405,11 +407,10 @@ def dryrun_terms(cand: Candidate, plan: ex.SweepPlan, shape: ProblemShape,
             reserve=jax.ShapeDtypeStruct((shape.n_scenarios,), jnp.float32))
     v = jax.ShapeDtypeStruct((shape.n_events, shape.n_campaigns),
                              jnp.float32)
+    fn = jax.jit(lambda v_, b_, r_: ex.execute_sweep(v_, b_, r_, concrete))
     try:
-        fn = jax.jit(lambda v_, b_, r_: ex.execute_sweep(v_, b_, r_,
-                                                         concrete))
         compiled = fn.lower(v, b, rules).compile()
-        cost = hlo_cost.analyze(compiled.as_text())
-    except Exception:
-        return None
+    except ValueError:
+        return None         # an illegal plan: the executor's own checks
+    cost = hlo_cost.analyze(compiled.as_text())
     return terms_from_cost(cost.flops, cost.bytes, cost.coll_wire_bytes, hw)

@@ -76,3 +76,14 @@ def test_dtype_bytes_table():
     assert hlo_cost._type_bytes("bf16[8]{0}") == 16
     assert hlo_cost._type_bytes("(f32[2,2], s32[4])") == 16 + 16
     assert hlo_cost._type_bytes("pred[]") == 1
+
+
+def test_hardware_spec_keyed_on_device_kind():
+    """Rates come from the JAX device_kind; a kind the table does not know
+    is an error, never another chip's peaks."""
+    from repro.launch.roofline import HardwareSpec
+    v5e = HardwareSpec.for_device_kind("TPU v5 lite")
+    assert (v5e.peak_flops, v5e.hbm_bw) == (197e12, 819e9)
+    assert HardwareSpec.for_device_kind(jax.devices()[0].device_kind)
+    with pytest.raises(ValueError, match="TPU v9 imaginary"):
+        HardwareSpec.for_device_kind("TPU v9 imaginary")

@@ -5,7 +5,7 @@ is a one-file change (ROADMAP housekeeping):
 
 * ``shard_map`` and ``axis_size`` — :mod:`repro.compat`;
 * ``Compiled.cost_analysis()`` — :func:`repro.compat.compiled_cost_analysis`
-  (jax 0.4.x returns a list-of-dicts, newer jax a dict);
+  (its return shape has changed between jax releases);
 * ``AxisType`` — the :mod:`repro.launch.mesh` ``_make_mesh`` shim.
 
 This test walks the ASTs of every module under ``src/repro/`` and fails on
@@ -93,3 +93,39 @@ def test_guard_catches_a_planted_violation(tmp_path):
         "    return jax.shard_map, ca, n\n")
     found = {what for _, what in _violations(planted, "planted.py")}
     assert len(found) == 5, found
+
+
+# asking for devices initialises the backend: on a TPU host that claims the
+# chip for the importing process, so no module may do it while imported
+_DEVICE_PROBES = {"devices", "local_devices", "device_count",
+                  "default_backend"}
+
+
+def _import_time_probes(tree):
+    """Calls of a device probe evaluated at import: in module-level
+    statements or in a function's default arguments."""
+    roots = [n for n in tree.body
+             if not isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef,
+                                   ast.ClassDef))]
+    for fn in ast.walk(tree):
+        if isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            roots += [d for d in fn.args.defaults + fn.args.kw_defaults
+                      if d is not None]
+    for root in roots:
+        for node in ast.walk(root):
+            if isinstance(node, ast.Call) and \
+                    isinstance(node.func, ast.Attribute) and \
+                    node.func.attr in _DEVICE_PROBES:
+                yield node.lineno, ast.unparse(node)
+
+
+def test_no_device_probe_at_import():
+    offenders = []
+    for path in sorted(SRC.rglob("*.py")):
+        tree = ast.parse(path.read_text(), filename=str(path))
+        offenders.extend(f"{path.relative_to(SRC)}:{line}: {call}"
+                         for line, call in _import_time_probes(tree))
+    assert not offenders, "\n".join(offenders)
+    planted = ast.parse("import jax\nON = jax.devices()\n"
+                        "def f(i=not jax.default_backend()):\n    pass\n")
+    assert len(list(_import_time_probes(planted))) == 2

@@ -135,6 +135,7 @@ def test_two_process_multihost_matches_single_process():
     procs = []
     for rank in range(2):
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"  # fake host devices, never a chip
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
         env["PYTHONPATH"] = SRC
         env["MH_RANK"] = str(rank)

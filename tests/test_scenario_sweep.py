@@ -371,6 +371,7 @@ def test_fused_sharded_retired_lanes_4dev():
         print("FUSED_SHARDED_4DEV_OK")
     """)
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"     # fake host devices, never a chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = str(Path(__file__).resolve().parents[1] / "src")
     out = subprocess.run([sys.executable, "-c", script], env=env,
@@ -428,8 +429,8 @@ def test_auto_resolve_never_selects_interpret_pallas(env):
 
 
 def resolve_on_tpu():
-    from repro.kernels.auction_resolve import ON_TPU
-    return ON_TPU
+    from repro.kernels import on_tpu
+    return on_tpu()
 
 
 def test_engine_sweep_fused_resolve_option(env):
@@ -605,6 +606,7 @@ def test_chunked_sharded_4dev_bitwise():
         print("CHUNKED_SHARDED_4DEV_OK")
     """)
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"     # fake host devices, never a chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = str(Path(__file__).resolve().parents[1] / "src")
     out = subprocess.run([sys.executable, "-c", script], env=env,
@@ -802,6 +804,7 @@ def test_scenario_chunked_sharded_4dev_bitwise():
         print("SCENARIO_CHUNKED_SHARDED_4DEV_OK")
     """)
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"     # fake host devices, never a chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = str(Path(__file__).resolve().parents[1] / "src")
     out = subprocess.run([sys.executable, "-c", script], env=env,

@@ -85,6 +85,7 @@ SCRIPT = textwrap.dedent("""
 @pytest.mark.slow
 def test_sharded_core_on_8_devices():
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"     # fake host devices, never a chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = SRC
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,

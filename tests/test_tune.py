@@ -376,6 +376,7 @@ def test_tuned_sharded_bitwise_4dev():
         print("TUNED_SHARDED_4DEV_OK")
     """)
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"     # fake host devices, never a chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = str(Path(__file__).resolve().parents[1] / "src")
     env["CI_SUBPROCESS"] = "1"
