@@ -1,0 +1,4 @@
+"""On-chip benchmark of the counterfactual sweep engine and service.
+
+``python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace
+<0|1>`` runs one cell of ``BENCHMARK.json``; see ``bench/run.py``."""
