@@ -83,7 +83,7 @@ from repro.core import auction
 from repro.core import crn
 from repro.core import segments as seg_lib
 from repro.core.types import AuctionRule, ScenarioOverlay, never_capped
-from repro import kernels
+from repro import kernels, obs
 from repro.kernels.auction_resolve import ops as resolve_ops
 from repro.launch.mesh import SweepMeshSpec
 
@@ -1022,16 +1022,18 @@ def _make_round_body(plan: SweepPlan, resolve: str, *, values_local,
                                               z_local, u_local)
                 rate_parts = psum(weighted_partials(winners, prices, n_hat,
                                                     hi_all, offset_fn()))
-            c_next, no_cap, n_next = jax.vmap(lane_pred)(
-                seg_lib.sum_blocks(rate_parts), b, s_hat, active, n_hat)
+            with jax.named_scope("predict"):
+                c_next, no_cap, n_next = jax.vmap(lane_pred)(
+                    seg_lib.sum_blocks(rate_parts), b, s_hat, active, n_hat)
             if two_pass:
                 block_parts = window_partials(act, keep, n_hat, n_next)
             else:
                 block_parts = psum(weighted_partials(winners, prices, n_hat,
                                                      n_next, offset_fn()))
             blk = seg_lib.sum_blocks(block_parts)
-        return jax.vmap(lane_comm)(blk, c_next, no_cap, n_next, s_hat,
-                                   active, cap, rnd, retired, bnds)
+        with jax.named_scope("commit"):
+            return jax.vmap(lane_comm)(blk, c_next, no_cap, n_next, s_hat,
+                                       active, cap, rnd, retired, bnds)
 
     return round_body
 
@@ -1409,16 +1411,24 @@ def _sweep_hoststream(stream: HostStream, budgets, rules, plan: SweepPlan,
                     stream.chunk((k + 1) * epc, (k + 2) * epc))
         return acc
 
+    def any_alive(keep) -> bool:
+        with obs.span("executor.sync"):
+            return bool(jax.device_get(jnp.any(keep)))
+
     keep = _hs_alive(core, n_events=n_events)
-    while bool(jax.device_get(jnp.any(keep))):
-        s_hat, active, cap, n_hat, rnd, retired, bnds = core
-        hi_all = jnp.full_like(n_hat, n_events)
-        rate_parts = stream_pass(active, keep, n_hat, hi_all)
-        c_next, no_cap, n_next = _hs_predict(rate_parts, b, s_hat, active,
-                                             n_hat, n_events=n_events)
-        block_parts = stream_pass(active, keep, n_hat, n_next)
-        core, keep = _hs_commit(core, keep, block_parts, c_next, no_cap,
-                                n_next, n_events=n_events)
+    alive = any_alive(keep)
+    while alive:
+        with obs.span("executor.round"):
+            s_hat, active, cap, n_hat, rnd, retired, bnds = core
+            hi_all = jnp.full_like(n_hat, n_events)
+            rate_parts = stream_pass(active, keep, n_hat, hi_all)
+            c_next, no_cap, n_next = _hs_predict(rate_parts, b, s_hat,
+                                                 active, n_hat,
+                                                 n_events=n_events)
+            block_parts = stream_pass(active, keep, n_hat, n_next)
+            core, keep = _hs_commit(core, keep, block_parts, c_next, no_cap,
+                                    n_next, n_events=n_events)
+            alive = any_alive(keep)
     return core
 
 
@@ -1500,48 +1510,52 @@ def execute_sweep(values, budgets, rules, plan: SweepPlan, *,
     model (:func:`resolve_auto_plan`); the resolved plan's outputs are
     bit-for-bit the default plan's.
     """
-    if needs_tuning(plan):
-        n_ev, n_c = (values.shape if isinstance(values, HostStream)
-                     else tuple(values.shape))
-        b = jnp.asarray(budgets)
-        plan = resolve_auto_plan(
-            plan, n_events=int(n_ev), n_campaigns=int(n_c),
-            n_scenarios=int(b.shape[0]) if b.ndim == 2 else 1)
-    if isinstance(values, HostStream) or (
-            plan.chunks is not None and plan.chunks.source == "host"):
-        check_host_stream(plan, overlay=overlay)
-        stream = values if isinstance(values, HostStream) \
-            else HostStream.from_array(values)
+    lanes = np.shape(budgets)[0] if np.ndim(budgets) == 2 else 1
+    with obs.span("executor.sweep", placement=plan.placement, lanes=lanes,
+                  events=values.shape[0]):
+        if needs_tuning(plan):
+            n_ev, n_c = (values.shape if isinstance(values, HostStream)
+                         else tuple(values.shape))
+            b = jnp.asarray(budgets)
+            plan = resolve_auto_plan(
+                plan, n_events=int(n_ev), n_campaigns=int(n_c),
+                n_scenarios=int(b.shape[0]) if b.ndim == 2 else 1)
+        if isinstance(values, HostStream) or (
+                plan.chunks is not None and plan.chunks.source == "host"):
+            check_host_stream(plan, overlay=overlay)
+            stream = values if isinstance(values, HostStream) \
+                else HostStream.from_array(values)
+            if plan.placement == "device":
+                rules_b = AuctionRule(
+                    multipliers=rules.multipliers[None, :],
+                    reserve=jnp.asarray(rules.reserve, jnp.float32)[None],
+                    kind=rules.kind)
+                core = _sweep_hoststream(
+                    stream, jnp.asarray(budgets)[None, :], rules_b,
+                    dataclasses.replace(plan, placement="batched"))
+                return tuple(x[0] for x in _unpack(core))
+            return _unpack(_sweep_hoststream(stream, budgets, rules, plan))
+        if plan.placement == "multihost":
+            return _sweep_multihost(values, budgets, rules, overlay, plan)
+        if plan.placement == "sharded":
+            return _sweep_sharded(values, budgets, rules, overlay, plan)
         if plan.placement == "device":
             rules_b = AuctionRule(
                 multipliers=rules.multipliers[None, :],
                 reserve=jnp.asarray(rules.reserve, jnp.float32)[None],
                 kind=rules.kind)
-            core = _sweep_hoststream(
-                stream, jnp.asarray(budgets)[None, :], rules_b,
+            if overlay is not None:
+                expand = lambda x: None if x is None else x[None]
+                overlay = dataclasses.replace(
+                    overlay, live_start=expand(overlay.live_start),
+                    live_stop=expand(overlay.live_stop),
+                    bid_sigma=expand(overlay.bid_sigma),
+                    part_prob=expand(overlay.part_prob))
+            out = _sweep_batched(
+                values, budgets[None, :], rules_b, overlay,
                 dataclasses.replace(plan, placement="batched"))
-            return tuple(x[0] for x in _unpack(core))
-        return _unpack(_sweep_hoststream(stream, budgets, rules, plan))
-    if plan.placement == "multihost":
-        return _sweep_multihost(values, budgets, rules, overlay, plan)
-    if plan.placement == "sharded":
-        return _sweep_sharded(values, budgets, rules, overlay, plan)
-    if plan.placement == "device":
-        rules_b = AuctionRule(
-            multipliers=rules.multipliers[None, :],
-            reserve=jnp.asarray(rules.reserve, jnp.float32)[None],
-            kind=rules.kind)
-        if overlay is not None:
-            expand = lambda x: None if x is None else x[None]
-            overlay = dataclasses.replace(
-                overlay, live_start=expand(overlay.live_start),
-                live_stop=expand(overlay.live_stop),
-                bid_sigma=expand(overlay.bid_sigma),
-                part_prob=expand(overlay.part_prob))
-        out = _sweep_batched(values, budgets[None, :], rules_b, overlay,
-                             dataclasses.replace(plan, placement="batched"))
-        return tuple(x[0] for x in out)
-    return _sweep_batched(values, budgets, rules, overlay, plan)
+            return tuple(x[0] for x in out)
+        return _sweep_batched(values, budgets, rules, overlay, plan)
 
 
 # ---------------------------------------------------------------------------

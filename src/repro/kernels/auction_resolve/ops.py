@@ -174,8 +174,9 @@ def round_fused(
     the (S, C) reduction the per-lane logic consumes."""
     n, c = values.shape
     block_size = -(-n // reduce_blocks)
-    tiles, t, tpb = block_tiles(values, block_size=block_size,
-                                block_t=block_t)
+    with jax.named_scope("relayout"):
+        tiles, t, tpb = block_tiles(values, block_size=block_size,
+                                    block_t=block_t)
     mult, act, res = _pad_scenario_state(multipliers, active, reserves)
     b = _pad_to(budgets.astype(jnp.float32), 128, 1)
     s = _pad_to(s_hat.astype(jnp.float32), 128, 1)
@@ -224,9 +225,10 @@ def sweep_partials(
     bit-for-bit. Only a resumable fold starts mid-block."""
     n, c = values.shape
     block_size = -(-n_events_global // reduce_blocks)
-    tiles, t, tpb = block_tiles(values, block_size=block_size,
-                                block_t=block_t,
-                                offset_in_block=offset_in_block)
+    with jax.named_scope("relayout"):
+        tiles, t, tpb = block_tiles(values, block_size=block_size,
+                                    block_t=block_t,
+                                    offset_in_block=offset_in_block)
     mult, act, res = _pad_scenario_state(multipliers, active, reserves)
     offset = jnp.asarray(offset, jnp.int32)
     place = jnp.stack([offset // block_size, offset, offset + n])

@@ -261,6 +261,7 @@ def round_fused_pallas(
             lane_i32, lane_i32, lane_i32,
         ],
         interpret=interpret,
+        name="round_fused",
     )(tiles, multipliers, active, budgets, s_hat, reserves, n_hat,
       lane_alive)
 
@@ -345,4 +346,5 @@ def sweep_partials_pallas(
         out_specs=pl.BlockSpec((s, g, c), lambda i, j: (0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((s, g, c), jnp.float32),
         interpret=interpret,
+        name="sweep_partials",
     )(tiles, multipliers, active, reserves, lo, hi, lane_alive, place)

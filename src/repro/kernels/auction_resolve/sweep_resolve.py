@@ -152,5 +152,6 @@ def sweep_resolve_pallas(
             jax.ShapeDtypeStruct((s, c), jnp.float32),
         ],
         interpret=interpret,
+        name="sweep_resolve",
     )(values, multipliers, active, reserves)
     return winners, prices, sums

@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.checkpoint.ckpt import (latest_step, restore_checkpoint,
                                    save_checkpoint)
 from repro.core import segments as seg_lib
@@ -252,8 +253,11 @@ class CounterfactualService:
         if self.store == "host":
             return HostStream(list(self._slabs))
         if self._values_version != self.log_version:
-            self._values = (self._slabs[0] if len(self._slabs) == 1
-                            else jnp.concatenate(self._slabs, axis=0))
+            if len(self._slabs) == 1:
+                self._values = self._slabs[0]
+            else:
+                with obs.span("serve.concat", rows=self._n_events):
+                    self._values = jnp.concatenate(self._slabs, axis=0)
             self._values_version = self.log_version
         return self._values
 
@@ -299,17 +303,19 @@ class CounterfactualService:
         if events.shape[0] == 0:
             raise ValueError("append needs at least one event row")
         check_append_alignment(self._chunk_spec, events.shape[0])
-        self.flush()
-        if self.store == "host":
-            events = np.asarray(jax.device_get(events), np.float32)
-        self._slabs.append(events)
-        self._n_events += events.shape[0]
-        self.log_version += 1
-        self.appends += 1
-        self._cache.clear()
-        for group in self._streams.values():
-            group.carry = self._fold(events, group.budgets, group.rules,
-                                     group.carry)
+        with obs.span("serve.append", rows=events.shape[0]) as span:
+            self.flush()
+            if self.store == "host":
+                events = np.asarray(jax.device_get(events), np.float32)
+            self._slabs.append(events)
+            self._n_events += events.shape[0]
+            self.log_version += 1
+            self.appends += 1
+            self._cache.clear()
+            span.set(version=self.log_version)
+            for group in self._streams.values():
+                group.carry = self._fold(events, group.budgets, group.rules,
+                                         group.carry)
         return self.log_version
 
     def _fold(self, slab, budgets, rules, carry) -> SweepCarry:
@@ -323,15 +329,14 @@ class CounterfactualService:
         n_new = slab.shape[0]
         spec = (self._host_chunks(n_new, int(carry.n_events_seen) + n_new)
                 if self.store == "host" else None)
-        if spec is not None:
-            plan = dataclasses.replace(self._stream_plan, chunks=spec)
-            _, carry = execute_sweep_resumable(
-                HostStream([np.asarray(slab, np.float32)]), budgets, rules,
-                plan, carry=carry)
-            return carry
-        _, carry = execute_sweep_resumable(
-            jnp.asarray(slab), budgets, rules, self._stream_plan,
-            carry=carry)
+        with obs.span("serve.fold", lanes=budgets.shape[0]):
+            if spec is None:
+                values, plan = jnp.asarray(slab), self._stream_plan
+            else:
+                values = HostStream([np.asarray(slab, np.float32)])
+                plan = dataclasses.replace(self._stream_plan, chunks=spec)
+            _, carry = execute_sweep_resumable(values, budgets, rules, plan,
+                                               carry=carry)
         return carry
 
     # -- admission batching (the exact path) -------------------------------
@@ -355,13 +360,15 @@ class CounterfactualService:
         """Admit one what-if scenario (defaults: the base design). Returns
         a :class:`Ticket`; concurrent asks queue until :meth:`flush` (or
         the first ``ticket.result()``) packs them into batched sweeps."""
-        rule, budgets = self._normalise(rule, budgets)
-        fp = design_fingerprint(kind=rule.kind, multipliers=rule.multipliers,
-                                reserve=rule.reserve, budgets=budgets)
-        ticket = Ticket(seq=self._seq, fingerprint=fp,
-                        label=label or f"ask{self._seq}", _service=self)
-        self._seq += 1
-        self._queue.append((ticket, rule, budgets))
+        with obs.span("serve.admit", seq=self._seq):
+            rule, budgets = self._normalise(rule, budgets)
+            fp = design_fingerprint(kind=rule.kind,
+                                    multipliers=rule.multipliers,
+                                    reserve=rule.reserve, budgets=budgets)
+            ticket = Ticket(seq=self._seq, fingerprint=fp,
+                            label=label or f"ask{self._seq}", _service=self)
+            self._seq += 1
+            self._queue.append((ticket, rule, budgets))
         return ticket
 
     def flush(self) -> int:
@@ -372,29 +379,40 @@ class CounterfactualService:
         if not self._queue:
             return 0
         pending, self._queue = self._queue, []
-        version = self.log_version
-        by_kind: Dict[str, List[Tuple[str, AuctionRule, jax.Array]]] = {}
-        seen = set()
-        for ticket, rule, budgets in pending:
-            if (version, ticket.fingerprint) in self._cache or \
-                    ticket.fingerprint in seen:
-                self.hits += 1
-                continue
-            self.misses += 1
-            seen.add(ticket.fingerprint)
-            by_kind.setdefault(rule.kind, []).append(
-                (ticket.fingerprint, rule, budgets))
-        for lanes in by_kind.values():
-            rules_s = sweep_lib.stack_rules([r for _, r, _ in lanes])
-            budgets_s = jnp.stack([b for _, _, b in lanes])
-            spend, caps = self._execute_batch(rules_s, budgets_s)
-            for i, (fp, _, _) in enumerate(lanes):
-                self._cache[(version, fp)] = (spend[i], caps[i])
-        for ticket, _, _ in pending:
-            spend_row, caps_row = self._cache[(version, ticket.fingerprint)]
-            ticket._answer = ServiceAnswer(final_spend=spend_row,
-                                           cap_times=caps_row,
-                                           log_version=version)
+        with obs.span("serve.flush", first_seq=pending[0][0].seq,
+                      tickets=len(pending)) as span:
+            version = self.log_version
+            by_kind: Dict[str, List[Tuple[str, AuctionRule,
+                                          jax.Array]]] = {}
+            seen = set()
+            for ticket, rule, budgets in pending:
+                if (version, ticket.fingerprint) in self._cache or \
+                        ticket.fingerprint in seen:
+                    continue
+                seen.add(ticket.fingerprint)
+                by_kind.setdefault(rule.kind, []).append(
+                    (ticket.fingerprint, rule, budgets))
+            misses = len(seen)
+            self.hits += len(pending) - misses
+            self.misses += misses
+            span.set(hits=len(pending) - misses, misses=misses)
+            answered = []
+            for lanes in by_kind.values():
+                with obs.span("serve.stack", lanes=len(lanes)):
+                    rules_s = sweep_lib.stack_rules([r for _, r, _ in lanes])
+                    budgets_s = jnp.stack([b for _, _, b in lanes])
+                answered.append((lanes, self._execute_batch(rules_s,
+                                                            budgets_s)))
+            with obs.span("serve.route"):
+                for lanes, (spend, caps) in answered:
+                    for i, (fp, _, _) in enumerate(lanes):
+                        self._cache[(version, fp)] = (spend[i], caps[i])
+                for ticket, _, _ in pending:
+                    spend_row, caps_row = self._cache[(version,
+                                                       ticket.fingerprint)]
+                    ticket._answer = ServiceAnswer(final_spend=spend_row,
+                                                   cap_times=caps_row,
+                                                   log_version=version)
         return len(pending)
 
     def _batch_plan(self, n_lanes: int) -> Tuple[SweepPlan, int]:
@@ -432,25 +450,30 @@ class CounterfactualService:
         returns host (S, C) final_spend / cap_times (padding stripped)."""
         n_lanes = budgets_s.shape[0]
         plan, n_pad = self._batch_plan(n_lanes)
-        if n_pad > n_lanes:
-            pad = lambda x: jnp.concatenate(
-                [x, jnp.repeat(x[:1], n_pad - n_lanes, axis=0)], axis=0)
-            rules_s = AuctionRule(multipliers=pad(rules_s.multipliers),
-                                  reserve=pad(rules_s.reserve),
-                                  kind=rules_s.kind)
-            budgets_s = pad(budgets_s)
-            if overlay is not None:
-                grow = lambda x: None if x is None else pad(x)
-                overlay = dataclasses.replace(
-                    overlay, live_start=grow(overlay.live_start),
-                    live_stop=grow(overlay.live_stop),
-                    bid_sigma=grow(overlay.bid_sigma),
-                    part_prob=grow(overlay.part_prob))
-        s_hat, cap_times, *_ = execute_sweep(self.values, budgets_s,
-                                             rules_s, plan, overlay=overlay)
-        self.batches += 1
-        spend = np.asarray(jax.device_get(s_hat))[:n_lanes]
-        caps = np.asarray(jax.device_get(cap_times))[:n_lanes]
+        with obs.span("serve.replay", lanes=n_lanes, padded_to=n_pad,
+                      events=self._n_events):
+            if n_pad > n_lanes:
+                with obs.span("serve.pad"):
+                    pad = lambda x: jnp.concatenate(
+                        [x, jnp.repeat(x[:1], n_pad - n_lanes, axis=0)],
+                        axis=0)
+                    rules_s = AuctionRule(
+                        multipliers=pad(rules_s.multipliers),
+                        reserve=pad(rules_s.reserve), kind=rules_s.kind)
+                    budgets_s = pad(budgets_s)
+                    if overlay is not None:
+                        grow = lambda x: None if x is None else pad(x)
+                        overlay = dataclasses.replace(
+                            overlay, live_start=grow(overlay.live_start),
+                            live_stop=grow(overlay.live_stop),
+                            bid_sigma=grow(overlay.bid_sigma),
+                            part_prob=grow(overlay.part_prob))
+            s_hat, cap_times, *_ = execute_sweep(
+                self.values, budgets_s, rules_s, plan, overlay=overlay)
+            self.batches += 1
+            with obs.span("serve.fetch"):
+                spend = np.asarray(jax.device_get(s_hat))[:n_lanes]
+                caps = np.asarray(jax.device_get(cap_times))[:n_lanes]
         return spend, caps
 
     # -- grid/family sweeps (what a service-bound engine delegates to) -----

@@ -10,6 +10,7 @@ and ``chip_smoke.py`` on the chip do.
 scenario lanes, and S = 1.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -119,6 +120,23 @@ def test_batched_sweep_program_fits_one_chip(one_chip, as_if_on_tpu):
     used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes)
     assert used < HBM_BYTES, used
+
+
+@pytest.mark.parametrize("s", [32, 1])
+def test_one_launch_program_names_its_kernel_and_relayout(one_chip,
+                                                          as_if_on_tpu, s):
+    """A device trace knows the fused round kernel by its instruction name,
+    ``round_fused`` or ``round_fused.<n>``, and the log's per-round
+    relayout by the ``relayout`` scope in its instructions' metadata."""
+    compiled = _compile(
+        lambda v, b, r: sweep_parallel(v, b, r).final_spend,
+        _sds((N, C), one_chip), _sds((s, C), one_chip),
+        _rules(s, one_chip))
+    text = compiled.as_text()
+    kernels = re.findall(r"%(round_fused(?:\.\d+)?) = [^\n]*"
+                         r'custom_call_target="tpu_custom_call"', text)
+    assert kernels, "no round_fused kernel instruction"
+    assert re.search(r'op_name="[^"]*/relayout/', text)
 
 
 def test_sharded_sweep_program_compiles_for_four_chips(topo, as_if_on_tpu):
