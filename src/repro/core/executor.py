@@ -849,14 +849,36 @@ def lane_round(winners, prices, b, s_hat, active, cap, n_hat, rnd, retired,
 # The one round body + the one while_loop
 # ---------------------------------------------------------------------------
 
+def log_layout(plan: SweepPlan, resolve: str, values_local, *,
+               n_events: int, resume_offset: int = 0):
+    """This device's event rows laid out once a program as the fused
+    kernels' block-relative tiles (:func:`resolve_ops.block_tiles`):
+    ``(tiles, t, tiles_per_block)`` where the round body hands the whole
+    local log to a kernel (the fused kernel, no event ``chunks=``), else
+    ``None``. The log never changes within a sweep, so every round and
+    every scenario chunk reads the same tiles. An event-chunk scan lays
+    each chunk out inside its step instead: laying out the whole log would
+    undo its O(events_per_chunk · C) working set."""
+    if plan.chunks is not None or not (
+            resolve == "fused" and fused_runs_kernel(plan.interpret)):
+        return None
+    block = seg_lib.reduce_block_size(n_events)
+    with jax.named_scope("relayout"):
+        return resolve_ops.block_tiles(values_local, block_size=block,
+                                       block_t=plan.block_t,
+                                       offset_in_block=resume_offset % block)
+
+
 def _make_round_body(plan: SweepPlan, resolve: str, *, values_local,
-                     rules_local, budgets_f32, n_events: int,
+                     layout, rules_local, budgets_f32, n_events: int,
                      n_campaigns: int, offset_fn, psum, use_interpret: bool,
                      overlay: Optional[ScenarioOverlay] = None,
                      noise=(None, None), resume_offset: int = 0):
     """Build the per-round body for any (placement, resolve, chunks) cell.
 
-    ``values_local`` is this device's event rows, ``offset_fn()`` the global
+    ``values_local`` is this device's event rows and ``layout`` their
+    :func:`log_layout`, made by the caller outside the round loop and any
+    scenario-chunk scan; ``offset_fn()`` the global
     index of its first row (0 off-mesh), ``psum`` the cross-device combiner
     (identity off-mesh). ``overlay`` carries this lane slice's (S_local, C)
     intervention fields (key already stripped), ``noise`` the (local_n, C)
@@ -950,25 +972,33 @@ def _make_round_body(plan: SweepPlan, resolve: str, *, values_local,
 
         return jax.vmap(one)(winners, prices, lo, hi)
 
+    kernel_kw = dict(n_events_global=n_events,
+                     reduce_blocks=seg_lib.REDUCE_BLOCKS, second_price=second,
+                     skip_retired=plan.skip_retired, interpret=use_interpret)
+
     def kernel_partials(v, active, keep, lo, hi, offset):
-        """One fused resolve+reduce kernel pass over ``v`` (NOT psum'd).
-        Shards and chunks start on canonical block boundaries; only a
-        resumable fold's rows start ``resume_offset % block`` into one."""
+        """One fused resolve+reduce kernel pass (NOT psum'd) over the event
+        chunk ``v``, laid out here, or with ``v=None`` over the whole local
+        log's ``layout``. Shards and chunks start on canonical block
+        boundaries; only a resumable fold's rows start
+        ``resume_offset % block`` into one."""
+        args = (rules_local.multipliers, active, rules_local.reserve, lo, hi,
+                keep, offset)
+        if v is None:
+            tiles, t, tpb = layout
+            return resolve_ops.sweep_partials_tiles(
+                tiles, *args, n_rows=local_n, t=t, tiles_per_block=tpb,
+                **kernel_kw)
         return resolve_ops.sweep_partials(
-            v, rules_local.multipliers, active, rules_local.reserve,
-            lo, hi, keep, offset, n_events_global=n_events,
-            reduce_blocks=seg_lib.REDUCE_BLOCKS,
-            offset_in_block=resume_offset % block, second_price=second,
-            skip_retired=plan.skip_retired, block_t=plan.block_t,
-            interpret=use_interpret)
+            v, *args, offset_in_block=resume_offset % block,
+            block_t=plan.block_t, **kernel_kw)
 
     def window_partials(act, keep, lo, hi):
         """The two-pass reduction: psum'd (S_l, G, C) partials of the global
         window [lo, hi) — whole-shard kernel pass, or a chunk scan."""
         offset = offset_fn()
         if chunks is None:
-            return psum(kernel_partials(values_local, act, keep, lo, hi,
-                                        offset))
+            return psum(kernel_partials(None, act, keep, lo, hi, offset))
         epc = chunks.events_per_chunk
         n_chunks = local_n // epc
         v_chunks = values_local.reshape(n_chunks, epc,
@@ -1006,12 +1036,14 @@ def _make_round_body(plan: SweepPlan, resolve: str, *, values_local,
         if one_launch:
             # resolve + rate partials + in-kernel prediction + block
             # partials in ONE launch; winners/prices never reach HBM
-            _, block_parts, c_next, no_cap, n_next = resolve_ops.round_fused(
-                values_local, rules_local.multipliers, act,
-                rules_local.reserve, b, s_hat, n_hat, keep,
-                reduce_blocks=seg_lib.REDUCE_BLOCKS, second_price=second,
-                skip_retired=plan.skip_retired, block_t=plan.block_t,
-                interpret=use_interpret)
+            tiles, t, tpb = layout
+            _, block_parts, c_next, no_cap, n_next = \
+                resolve_ops.round_fused_tiles(
+                    tiles, rules_local.multipliers, act, rules_local.reserve,
+                    b, s_hat, n_hat, keep, n_events=n_events, t=t,
+                    tiles_per_block=tpb, reduce_blocks=seg_lib.REDUCE_BLOCKS,
+                    second_price=second, skip_retired=plan.skip_retired,
+                    interpret=use_interpret)
             blk = seg_lib.sum_blocks(block_parts)
         else:
             hi_all = jnp.full_like(n_hat, n_events)
@@ -1112,11 +1144,14 @@ def _run_lanes(plan: SweepPlan, resolve: str, *, values_local, mult_local,
     analogue of the event-chunk exactness argument.
     """
     s_local = budgets_f32.shape[0]
+    # laid out once, before the scenario-chunk scan and the round loop
+    layout = log_layout(plan, resolve, values_local, n_events=n_events)
 
     def run(b_c, mult_c, res_c, ol_c):
         rules_c = AuctionRule(multipliers=mult_c, reserve=res_c, kind=kind)
         round_body = _make_round_body(
-            plan, resolve, values_local=values_local, rules_local=rules_c,
+            plan, resolve, values_local=values_local, layout=layout,
+            rules_local=rules_c,
             budgets_f32=b_c, n_events=n_events, n_campaigns=n_campaigns,
             offset_fn=offset_fn, psum=psum, use_interpret=use_interpret,
             overlay=ol_c, noise=noise)
@@ -1512,7 +1547,7 @@ def execute_sweep(values, budgets, rules, plan: SweepPlan, *,
     """
     lanes = np.shape(budgets)[0] if np.ndim(budgets) == 2 else 1
     with obs.span("executor.sweep", placement=plan.placement, lanes=lanes,
-                  events=values.shape[0]):
+                  events=values.shape[0]) as sweep:
         if needs_tuning(plan):
             n_ev, n_c = (values.shape if isinstance(values, HostStream)
                          else tuple(values.shape))
@@ -1520,6 +1555,9 @@ def execute_sweep(values, budgets, rules, plan: SweepPlan, *,
             plan = resolve_auto_plan(
                 plan, n_events=int(n_ev), n_campaigns=int(n_c),
                 n_scenarios=int(b.shape[0]) if b.ndim == 2 else 1)
+        # where the fused kernels' tiles are laid out (log_layout): once
+        # before the round loop, or per event chunk inside the chunk scan
+        sweep.set(layout="once" if plan.chunks is None else "per_chunk")
         if isinstance(values, HostStream) or (
                 plan.chunks is not None and plan.chunks.source == "host"):
             check_host_stream(plan, overlay=overlay)
@@ -1641,7 +1679,10 @@ def _resume_batched(values_new, budgets, rules, s_hat0, active0, cap0,
                           reserve=jnp.asarray(rules.reserve, jnp.float32),
                           kind=rules.kind)
     round_body = _make_round_body(
-        plan, resolve, values_local=values_new, rules_local=rules_c,
+        plan, resolve, values_local=values_new,
+        layout=log_layout(plan, resolve, values_new, n_events=n_total,
+                          resume_offset=n_seen),
+        rules_local=rules_c,
         budgets_f32=budgets.astype(jnp.float32), n_events=n_total,
         n_campaigns=n_campaigns, offset_fn=lambda: n_seen,
         psum=lambda x: x, use_interpret=use_interpret,

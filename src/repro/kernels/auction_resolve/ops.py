@@ -147,6 +147,46 @@ def _pad_scenario_state(multipliers, active, reserves):
 
 
 @functools.partial(jax.jit, static_argnames=(
+    "n_events", "t", "tiles_per_block", "reduce_blocks", "second_price",
+    "skip_retired", "interpret"))
+def round_fused_tiles(
+    tiles: jax.Array,            # block_tiles(values) — the laid-out log
+    multipliers: jax.Array,      # (S, C)
+    active: jax.Array,           # (S, C) bool — current activation sets
+    reserves: jax.Array,         # (S,) or scalar
+    budgets: jax.Array,          # (S, C)
+    s_hat: jax.Array,            # (S, C) — spends so far
+    n_hat: jax.Array,            # (S,) int32 — current event frontier
+    lane_alive: jax.Array,       # (S,) bool — False = Algorithm-2 lane frozen
+    *,
+    n_events: int,               # N, the rows the tiles were laid out from
+    t: int,                      # rows a tile, from block_tiles
+    tiles_per_block: int,        # from block_tiles
+    reduce_blocks: int,          # repro.core.segments.REDUCE_BLOCKS
+    second_price: bool = False,
+    skip_retired: bool = True,
+    interpret: bool | None = None,
+):
+    """:func:`round_fused` on a log already laid out by :func:`block_tiles`
+    (``block_size = ceil(n_events / reduce_blocks)``, no offset): the entry
+    a round loop calls, so that the layout is made once, outside it."""
+    c = multipliers.shape[1]
+    block_size = -(-n_events // reduce_blocks)
+    mult, act, res = _pad_scenario_state(multipliers, active, reserves)
+    b = _pad_to(budgets.astype(jnp.float32), 128, 1)
+    s = _pad_to(s_hat.astype(jnp.float32), 128, 1)
+    rate_parts, block_parts, c_next, no_cap, n_next = round_fused_pallas(
+        tiles, mult, act, b, s, res, jnp.asarray(n_hat, jnp.int32),
+        lane_alive.astype(jnp.int32),
+        n_events=n_events, block_size=block_size,
+        num_reduce_blocks=reduce_blocks, tiles_per_block=tiles_per_block,
+        second_price=second_price, skip_retired=skip_retired, block_t=t,
+        interpret=use_interpret(interpret))
+    return (rate_parts[:, :, :c], block_parts[:, :, :c],
+            jnp.minimum(c_next, c - 1), no_cap != 0, n_next)
+
+
+@functools.partial(jax.jit, static_argnames=(
     "reduce_blocks", "second_price", "skip_retired", "block_t", "interpret"))
 def round_fused(
     values: jax.Array,           # (N, C) — shared valuation matrix
@@ -167,28 +207,63 @@ def round_fused(
     """One fused Algorithm-2 round for S scenario lanes (see
     ``round_fused.py``): resolve + rate partials + cap-out prediction +
     block partials in a single kernel launch, with retired lanes skipped.
+    Lays ``values`` out, then calls :func:`round_fused_tiles`.
 
     Returns ``(rate_partials (S, G, C), block_partials (S, G, C),
     c_next (S,) i32, no_cap (S,) bool, n_next (S,) i32)`` — sum a partials
     tensor over its G axis (:func:`repro.core.segments.sum_blocks`) to get
     the (S, C) reduction the per-lane logic consumes."""
-    n, c = values.shape
-    block_size = -(-n // reduce_blocks)
+    n = values.shape[0]
     with jax.named_scope("relayout"):
-        tiles, t, tpb = block_tiles(values, block_size=block_size,
+        tiles, t, tpb = block_tiles(values, block_size=-(-n // reduce_blocks),
                                     block_t=block_t)
+    return round_fused_tiles(
+        tiles, multipliers, active, reserves, budgets, s_hat, n_hat,
+        lane_alive, n_events=n, t=t, tiles_per_block=tpb,
+        reduce_blocks=reduce_blocks, second_price=second_price,
+        skip_retired=skip_retired, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_rows", "n_events_global", "t", "tiles_per_block", "reduce_blocks",
+    "second_price", "skip_retired", "interpret"))
+def sweep_partials_tiles(
+    tiles: jax.Array,            # block_tiles(values, offset_in_block=...)
+    multipliers: jax.Array,      # (S, C)
+    active: jax.Array,           # (S, C) bool
+    reserves: jax.Array,         # (S,) or scalar
+    lo: jax.Array,               # (S,) int32 — global weight window [lo, hi)
+    hi: jax.Array,               # (S,) int32
+    lane_alive: jax.Array,       # (S,) bool
+    offset: jax.Array,           # () int32 — global index of values[0]
+    *,
+    n_rows: int,                 # rows of the slice the tiles hold
+    n_events_global: int,        # N across all shards (canonical grid base)
+    t: int,                      # rows a tile, from block_tiles
+    tiles_per_block: int,        # from block_tiles
+    reduce_blocks: int,
+    second_price: bool = False,
+    skip_retired: bool = True,
+    interpret: bool | None = None,
+):
+    """:func:`sweep_partials` on a slice already laid out by
+    :func:`block_tiles` (``block_size = ceil(n_events_global /
+    reduce_blocks)``, the slice's ``offset_in_block``): the entry a round
+    loop calls, so that the layout is made once, outside it."""
+    c = multipliers.shape[1]
+    block_size = -(-n_events_global // reduce_blocks)
     mult, act, res = _pad_scenario_state(multipliers, active, reserves)
-    b = _pad_to(budgets.astype(jnp.float32), 128, 1)
-    s = _pad_to(s_hat.astype(jnp.float32), 128, 1)
-    rate_parts, block_parts, c_next, no_cap, n_next = round_fused_pallas(
-        tiles, mult, act, b, s, res, jnp.asarray(n_hat, jnp.int32),
-        lane_alive.astype(jnp.int32),
-        n_events=n, block_size=block_size, num_reduce_blocks=reduce_blocks,
-        tiles_per_block=tpb, second_price=second_price,
+    offset = jnp.asarray(offset, jnp.int32)
+    place = jnp.stack([offset // block_size, offset, offset + n_rows])
+    parts = sweep_partials_pallas(
+        tiles, mult, act, res,
+        jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32),
+        lane_alive.astype(jnp.int32), place,
+        block_size=block_size, num_reduce_blocks=reduce_blocks,
+        tiles_per_block=tiles_per_block, second_price=second_price,
         skip_retired=skip_retired, block_t=t,
         interpret=use_interpret(interpret))
-    return (rate_parts[:, :, :c], block_parts[:, :, :c],
-            jnp.minimum(c_next, c - 1), no_cap != 0, n_next)
+    return parts[:, :, :c]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -222,22 +297,15 @@ def sweep_partials(
     canonical blocks (``offset_in_block=0``), so their block-relative tiles
     are the single-device launch's tiles and the output is bit-for-bit the
     slice of its partials — which is what keeps every placement
-    bit-for-bit. Only a resumable fold starts mid-block."""
-    n, c = values.shape
-    block_size = -(-n_events_global // reduce_blocks)
+    bit-for-bit. Only a resumable fold starts mid-block. Lays ``values``
+    out, then calls :func:`sweep_partials_tiles`."""
+    n = values.shape[0]
     with jax.named_scope("relayout"):
-        tiles, t, tpb = block_tiles(values, block_size=block_size,
-                                    block_t=block_t,
-                                    offset_in_block=offset_in_block)
-    mult, act, res = _pad_scenario_state(multipliers, active, reserves)
-    offset = jnp.asarray(offset, jnp.int32)
-    place = jnp.stack([offset // block_size, offset, offset + n])
-    parts = sweep_partials_pallas(
-        tiles, mult, act, res,
-        jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32),
-        lane_alive.astype(jnp.int32), place,
-        block_size=block_size, num_reduce_blocks=reduce_blocks,
-        tiles_per_block=tpb, second_price=second_price,
-        skip_retired=skip_retired, block_t=t,
-        interpret=use_interpret(interpret))
-    return parts[:, :, :c]
+        tiles, t, tpb = block_tiles(
+            values, block_size=-(-n_events_global // reduce_blocks),
+            block_t=block_t, offset_in_block=offset_in_block)
+    return sweep_partials_tiles(
+        tiles, multipliers, active, reserves, lo, hi, lane_alive, offset,
+        n_rows=n, n_events_global=n_events_global, t=t, tiles_per_block=tpb,
+        reduce_blocks=reduce_blocks, second_price=second_price,
+        skip_retired=skip_retired, interpret=interpret)

@@ -5,9 +5,11 @@ import numpy as np
 import pytest
 
 from repro.kernels.auction_resolve import (auction_resolve,
-                                           auction_resolve_ref,
+                                           auction_resolve_ref, block_tiles,
                                            fused_partials_ref, round_fused,
-                                           round_fused_ref, sweep_partials,
+                                           round_fused_ref, round_fused_tiles,
+                                           sweep_partials,
+                                           sweep_partials_tiles,
                                            sweep_resolve, sweep_resolve_ref)
 from repro.kernels.capped_scan import capped_scan, capped_scan_ref
 from repro.kernels.flash_attention import flash_attention, flash_attention_ref
@@ -209,6 +211,62 @@ def test_sweep_partials_matches_ref_with_offset(offset, ndev):
     outside[g_lo:g_hi + 1] = False
     if outside.any():
         assert float(np.abs(np.asarray(parts_k)[:, outside]).max()) == 0.0
+
+
+@pytest.mark.parametrize("s,n,c,sp,blk", [
+    (1, 512, 40, False, 256),
+    (5, 1000, 33, True, 128),        # ragged N and C
+    (4, 300, 7, False, 64),          # blocks smaller than a tile
+])
+def test_round_fused_tiles_is_the_values_entry(s, n, c, sp, blk):
+    """The tile entry a round loop calls, given ``block_tiles`` output, is
+    bitwise the values entry that lays the log out on every call."""
+    v, mult, act, res, b, s_hat, n_hat = _fused_inputs(s, n, c, seed=5)
+    alive = jnp.arange(s) % 3 != 1
+    tiles, t, tpb = block_tiles(v, block_size=-(-n // 32), block_t=blk)
+    got = round_fused_tiles(tiles, mult, act, res, b, s_hat, n_hat, alive,
+                            n_events=n, t=t, tiles_per_block=tpb,
+                            reduce_blocks=32, second_price=sp,
+                            interpret=True)
+    want = round_fused(v, mult, act, res, b, s_hat, n_hat, alive,
+                       reduce_blocks=32, second_price=sp, block_t=blk,
+                       interpret=True)
+    for a, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(w))
+
+
+@pytest.mark.parametrize("offset,local_n,blk", [
+    (0, 2048, 256),                  # the whole log, one device
+    (512, 512, 128),                 # a shard on block boundaries
+    (100, 300, 64),                  # a resumable fold: starts mid-block
+    (1900, 148, 256),                # a fold's tail, mid-block to the end
+])
+def test_sweep_partials_tiles_is_the_values_entry(offset, local_n, blk):
+    """The tile entry, given ``block_tiles`` output at the slice's
+    ``offset_in_block``, is bitwise the values entry, and both place the
+    slice's partials on the global grid as the oracle does."""
+    s, n_global, c = 4, 2048, 20
+    v, mult, act, res, b, s_hat, n_hat = _fused_inputs(s, n_global, c)
+    v_local = v[offset:offset + local_n]
+    block_size = -(-n_global // 32)
+    lo, hi = n_hat, jnp.full_like(n_hat, n_global)
+    alive = jnp.ones((s,), bool)
+    tiles, t, tpb = block_tiles(v_local, block_size=block_size, block_t=blk,
+                                offset_in_block=offset % block_size)
+    got = sweep_partials_tiles(
+        tiles, mult, act, res, lo, hi, alive, jnp.int32(offset),
+        n_rows=local_n, n_events_global=n_global, t=t, tiles_per_block=tpb,
+        reduce_blocks=32, interpret=True)
+    want = sweep_partials(
+        v_local, mult, act, res, lo, hi, alive, jnp.int32(offset),
+        n_events_global=n_global, reduce_blocks=32,
+        offset_in_block=offset % block_size, block_t=blk, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    ref = fused_partials_ref(v_local, mult, act, res, lo, hi,
+                             block_size=block_size, reduce_blocks=32,
+                             index_offset=offset)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-5,
+                               atol=1e-5)
 
 
 @pytest.mark.parametrize("n,c,blk", [

@@ -151,6 +151,19 @@ def test_host_store_replays_record_host_rounds(env, traced):
                                                          for r in rounds)
 
 
+@pytest.mark.parametrize("chunks,layout", [(None, "once"),
+                                           (_EPC, "per_chunk")])
+def test_sweep_span_says_where_the_log_is_laid_out(env, traced, chunks,
+                                                   layout):
+    """``executor.sweep`` carries ``layout``: "once" where the round loop
+    reads tiles laid out before it, "per_chunk" where an event-chunk scan
+    lays each chunk out inside its step."""
+    engine = CounterfactualEngine(env.values, env.budgets)
+    engine.sweep(engine.grid(bid_scales=(1.0, 1.25)), chunks=chunks)
+    sweep, = _by_name(obs.records(), "executor.sweep")
+    assert sweep.attrs["layout"] == layout
+
+
 def test_a_new_session_starts_a_fresh_buffer(tmp_path):
     obs.clear()
     jax.profiler.start_trace(str(tmp_path / "one"))

@@ -122,12 +122,57 @@ def test_batched_sweep_program_fits_one_chip(one_chip, as_if_on_tpu):
     assert used < HBM_BYTES, used
 
 
+def _computations(text):
+    """HLO text -> {computation name: its instruction lines}."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+def _called(line):
+    names = re.findall(r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)",
+                       line)
+    for group in re.findall(
+            r"(?:called_computations|branch_computations)=\{([^}]*)\}",
+            line):
+        names += [x.strip().lstrip("%") for x in group.split(",")]
+    return names
+
+
+def _relayout_in_and_out_of_loops(compiled):
+    """Instructions scoped ``relayout``: (inside a while loop's body or a
+    computation it calls, elsewhere). A scenario-chunk ``lax.map`` is a
+    while loop too, so "inside" covers it."""
+    comps = _computations(compiled.as_text())
+    stack = [b for lines in comps.values() for line in lines
+             for b in re.findall(r"body=%?([\w.\-]+)", line)]
+    assert stack, "no while loop in the program"
+    looped = set()
+    while stack:
+        name = stack.pop()
+        if name in looped or name not in comps:
+            continue
+        looped.add(name)
+        stack += [c for line in comps[name] for c in _called(line)]
+    scoped = lambda names: [line.strip() for n in names for line in comps[n]
+                            if re.search(r'op_name="[^"]*/relayout/', line)]
+    return scoped(looped), scoped(set(comps) - looped)
+
+
 @pytest.mark.parametrize("s", [32, 1])
 def test_one_launch_program_names_its_kernel_and_relayout(one_chip,
                                                           as_if_on_tpu, s):
     """A device trace knows the fused round kernel by its instruction name,
-    ``round_fused`` or ``round_fused.<n>``, and the log's per-round
-    relayout by the ``relayout`` scope in its instructions' metadata."""
+    ``round_fused`` or ``round_fused.<n>``, and the log's layout into
+    block tiles, made once a sweep before the round loop, by the
+    ``relayout`` scope in its instructions' metadata."""
     compiled = _compile(
         lambda v, b, r: sweep_parallel(v, b, r).final_spend,
         _sds((N, C), one_chip), _sds((s, C), one_chip),
@@ -139,9 +184,26 @@ def test_one_launch_program_names_its_kernel_and_relayout(one_chip,
     assert re.search(r'op_name="[^"]*/relayout/', text)
 
 
+@pytest.mark.parametrize("s,scenario_chunks", [(32, None), (1, None),
+                                               (32, 8)])
+def test_one_launch_program_lays_the_log_out_outside_its_loops(
+        one_chip, as_if_on_tpu, s, scenario_chunks):
+    """The log never changes within a sweep: its layout sits before the
+    round loop and before the scenario-chunk scan, not in their bodies."""
+    compiled = _compile(
+        lambda v, b, r: sweep_parallel(
+            v, b, r, scenario_chunks=scenario_chunks).final_spend,
+        _sds((N, C), one_chip), _sds((s, C), one_chip),
+        _rules(s, one_chip))
+    inside, outside = _relayout_in_and_out_of_loops(compiled)
+    assert not inside, inside[:3]
+    assert any(" gather(" in line for line in outside), outside[:3]
+
+
 def test_sharded_sweep_program_compiles_for_four_chips(topo, as_if_on_tpu):
     """driver="sharded" on the 2x2 host: events over 4 chips, 250,000 per
-    shard, the fused partials kernel on each, one psum per reduction."""
+    shard, the fused partials kernel on each, one psum per reduction; each
+    shard's rows are laid out once, outside the round loop."""
     mesh = Mesh(np.array(topo.devices), ("data",))
     spec = SweepMeshSpec(mesh, event_axes=("data",))
     rep = NamedSharding(mesh, P())
@@ -151,3 +213,6 @@ def test_sharded_sweep_program_compiles_for_four_chips(topo, as_if_on_tpu):
         _sds((N, C), NamedSharding(mesh, P("data", None))),
         _sds((32, C), rep), _rules(32, rep))
     assert "all-reduce" in compiled.as_text()
+    inside, outside = _relayout_in_and_out_of_loops(compiled)
+    assert not inside, inside[:3]
+    assert any(" gather(" in line for line in outside), outside[:3]
