@@ -972,6 +972,13 @@ def _make_round_body(plan: SweepPlan, resolve: str, *, values_local,
 
         return jax.vmap(one)(winners, prices, lo, hi)
 
+    def exchange(parts):
+        """``psum`` of a round's partials, scoped ``exchange`` so that a
+        device trace's op metadata names the collectives (nothing under
+        the scope off-mesh, where ``psum`` is the identity)."""
+        with jax.named_scope("exchange"):
+            return psum(parts)
+
     kernel_kw = dict(n_events_global=n_events,
                      reduce_blocks=seg_lib.REDUCE_BLOCKS, second_price=second,
                      skip_retired=plan.skip_retired, interpret=use_interpret)
@@ -998,7 +1005,7 @@ def _make_round_body(plan: SweepPlan, resolve: str, *, values_local,
         window [lo, hi) — whole-shard kernel pass, or a chunk scan."""
         offset = offset_fn()
         if chunks is None:
-            return psum(kernel_partials(None, act, keep, lo, hi, offset))
+            return exchange(kernel_partials(None, act, keep, lo, hi, offset))
         epc = chunks.events_per_chunk
         n_chunks = local_n // epc
         v_chunks = values_local.reshape(n_chunks, epc,
@@ -1024,7 +1031,7 @@ def _make_round_body(plan: SweepPlan, resolve: str, *, values_local,
         parts, _ = jax.lax.scan(
             step, acc0, (v_chunks, chunked(z_local), chunked(u_local),
                          jnp.arange(n_chunks, dtype=jnp.int32)))
-        return psum(parts)
+        return exchange(parts)
 
     def round_body(core, keep):
         s_hat, active, cap, n_hat, rnd, retired, bnds = core
@@ -1052,16 +1059,16 @@ def _make_round_body(plan: SweepPlan, resolve: str, *, values_local,
             else:
                 winners, prices = resolve_all(values_local, act, offset_fn(),
                                               z_local, u_local)
-                rate_parts = psum(weighted_partials(winners, prices, n_hat,
-                                                    hi_all, offset_fn()))
+                rate_parts = exchange(weighted_partials(
+                    winners, prices, n_hat, hi_all, offset_fn()))
             with jax.named_scope("predict"):
                 c_next, no_cap, n_next = jax.vmap(lane_pred)(
                     seg_lib.sum_blocks(rate_parts), b, s_hat, active, n_hat)
             if two_pass:
                 block_parts = window_partials(act, keep, n_hat, n_next)
             else:
-                block_parts = psum(weighted_partials(winners, prices, n_hat,
-                                                     n_next, offset_fn()))
+                block_parts = exchange(weighted_partials(
+                    winners, prices, n_hat, n_next, offset_fn()))
             blk = seg_lib.sum_blocks(block_parts)
         with jax.named_scope("commit"):
             return jax.vmap(lane_comm)(blk, c_next, no_cap, n_next, s_hat,
@@ -1558,6 +1565,9 @@ def execute_sweep(values, budgets, rules, plan: SweepPlan, *,
         # where the fused kernels' tiles are laid out (log_layout): once
         # before the round loop, or per event chunk inside the chunk scan
         sweep.set(layout="once" if plan.chunks is None else "per_chunk")
+        if plan.placement == "sharded":
+            shards = plan.mesh.event_device_count
+            sweep.set(shards=shards, local_events=values.shape[0] // shards)
         if isinstance(values, HostStream) or (
                 plan.chunks is not None and plan.chunks.source == "host"):
             check_host_stream(plan, overlay=overlay)

@@ -164,6 +164,24 @@ def test_sweep_span_says_where_the_log_is_laid_out(env, traced, chunks,
     assert sweep.attrs["layout"] == layout
 
 
+@pytest.mark.parametrize("driver", ["batched", "sharded"])
+def test_sweep_span_says_how_the_log_is_sharded(env, traced, driver):
+    """Under ``placement="sharded"`` ``executor.sweep`` also carries
+    ``shards`` (devices on the event axes) and ``local_events`` (rows a
+    shard holds); elsewhere its attributes are as they were."""
+    from repro.launch.mesh import SweepMeshSpec
+    mesh = SweepMeshSpec.for_devices() if driver == "sharded" else None
+    engine = CounterfactualEngine(env.values, env.budgets)
+    engine.sweep(engine.grid(bid_scales=(1.0, 1.25)), driver=driver,
+                 mesh=mesh)
+    sweep, = _by_name(obs.records(), "executor.sweep")
+    want = {"placement": driver, "lanes": 2, "events": _N, "layout": "once"}
+    if mesh is not None:
+        shards = mesh.event_device_count
+        want.update(shards=shards, local_events=_N // shards)
+    assert sweep.attrs == want
+
+
 def test_a_new_session_starts_a_fresh_buffer(tmp_path):
     obs.clear()
     jax.profiler.start_trace(str(tmp_path / "one"))

@@ -216,3 +216,33 @@ def test_sharded_sweep_program_compiles_for_four_chips(topo, as_if_on_tpu):
     inside, outside = _relayout_in_and_out_of_loops(compiled)
     assert not inside, inside[:3]
     assert any(" gather(" in line for line in outside), outside[:3]
+
+
+def test_bigday_sharded_sweep_fits_four_chips(topo, as_if_on_tpu):
+    """The four-chip benchmark cell's program (bigday-x4.grid8): the §7.1
+    market at N = 3.2e7, 8e6 rows a chip, S = 8. Arguments, temp and
+    output fit 14 GiB a chip; the round's only collectives are its two
+    all-reduces of (S, 32, C) partials, scoped ``exchange``: no step
+    gathers the log."""
+    n, s = 32_000_000, 8
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    spec = SweepMeshSpec(mesh, event_axes=("data",))
+    rep = NamedSharding(mesh, P())
+    compiled = _compile(
+        lambda v, b, r: sweep_parallel(v, b, r, driver="sharded",
+                                       mesh=spec).final_spend,
+        _sds((n, C), NamedSharding(mesh, P("data", None))),
+        _sds((s, C), rep), _rules(s, rep))
+    ma = compiled.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes)
+    assert ma.argument_size_in_bytes >= n // 4 * C * 4    # one shard
+    assert used <= 14 * 2 ** 30, used
+    collectives = [line.strip() for line in compiled.as_text().splitlines()
+                   if re.search(r" (all-reduce|all-gather|all-to-all|"
+                                r"collective-permute|reduce-scatter)"
+                                r"(-start)?\(", line)]
+    assert len(collectives) == 2, collectives
+    for line in collectives:
+        assert re.search(rf"= f32\[{s},32,{C}\]\S* all-reduce\(", line), line
+        assert re.search(r'op_name="[^"]*/exchange/', line), line
