@@ -1565,6 +1565,11 @@ def execute_sweep(values, budgets, rules, plan: SweepPlan, *,
         # where the fused kernels' tiles are laid out (log_layout): once
         # before the round loop, or per event chunk inside the chunk scan
         sweep.set(layout="once" if plan.chunks is None else "per_chunk")
+        # the fused kernels skip every grid step whose tile holds no row of
+        # its lane's window (kernels/auction_resolve/round_fused.py)
+        if pick_resolve(plan.resolve) == "fused" \
+                and fused_runs_kernel(plan.interpret):
+            sweep.set(tile_skip="window")
         if plan.placement == "sharded":
             shards = plan.mesh.event_device_count
             sweep.set(shards=shards, local_events=values.shape[0] // shards)

@@ -49,14 +49,28 @@ identical partials, as the single-device launch. That is what keeps
 the same sequential order as :func:`repro.core.segments.sum_blocks`, the
 sum every other placement uses.
 
-Converged-lane skipping: both kernels take a per-scenario ``lane_alive``
-mask and (statically, ``skip_retired=True``) predicate each (tile, scenario)
-grid step on it with ``pl.when`` — a lane whose Algorithm-2 state is frozen
-contributes no tile work, so a round's wall-clock tracks the lanes still
-running rather than S. Frozen lanes' outputs are whatever the zero-init left
-there; the drivers discard frozen lanes' updates by select either way, so
-skipping cannot change results (asserted masked-vs-unmasked bit-identical in
-``tests/test_scenario_sweep.py`` / ``tests/test_sharded_sweep.py``).
+Step skipping: a (tile, lane) grid step does tile work only where
+:func:`_step_live` says so — its lane is alive (the per-scenario
+``lane_alive`` mask; statically, ``skip_retired=True``: a lane whose
+Algorithm-2 state is frozen contributes nothing, and the drivers discard
+its outputs by select) and the tile's rows, clipped at their canonical
+block's end, meet the lane's weight window (``[n_hat, N)`` in the rate
+pass, ``[n_hat, n_next)`` in the block pass, ``[lo, hi)`` within the
+slice for :func:`sweep_partials_pallas`). Both kernels call that one
+predicate, so every placement skips the same steps. A skipped step would
+add ``onehot · (price · 0)`` to the partials: prices are finite, layout
+padding rows are zeros, and the partials start at +0 and only gain
+non-negative terms, so the product is +0.0 and skipping it leaves every
+output bitwise what the full grid gives. The tiles behind the earliest
+window of any lane are not even fetched: a scalar-prefetched index map
+clamps each step's tile to ``[first, last]`` (:func:`_first_live_tile`),
+so a run of skipped steps repeats one block index and Pallas issues no
+copy for it; a slice wholly behind every window clamps to its own last
+tile. Retired lanes' skipping is asserted masked-vs-unmasked
+bit-identical in ``tests/test_scenario_sweep.py`` /
+``tests/test_sharded_sweep.py``; window skipping in
+``tests/test_kernels.py``, where every step that runs with all weights 0
+is poisoned with NaN.
 
 TPU layout: per-lane scalars (reserves, ``n_hat``, ``lane_alive``, window
 bounds, the predicted ``(c_next, no_cap, n_next)``) live in SMEM; per-lane
@@ -72,6 +86,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.auction_resolve.sweep_resolve import SMEM, resolve_tile
 
@@ -83,6 +98,47 @@ def _tile_rows(tile, *, tiles_per_block: int, block_t: int):
     rel = (tile % tiles_per_block) * block_t + jax.lax.broadcasted_iota(
         jnp.int32, (block_t, 1), 0)
     return b, rel
+
+
+def _step_live(tile, lo, hi, alive, *, skip_retired: bool, first_block,
+               block_size: int, block_t: int, tiles_per_block: int):
+    """Whether a (tile, lane) grid step does tile work: the tile's global
+    rows ``[start, end)`` — clipped at its canonical block's end, where
+    the layout's padding starts — meet the lane's window ``[lo, hi)``,
+    and, with ``skip_retired``, the lane is alive. ``first_block`` is the
+    canonical block of the slice's first tile. Scalar int32 work only."""
+    block_start = (first_block + tile // tiles_per_block) * block_size
+    start = block_start + (tile % tiles_per_block) * block_t
+    end = jnp.minimum(start + block_t, block_start + block_size)
+    live = jnp.maximum(start, lo) < jnp.minimum(end, hi)
+    if skip_retired:
+        live = live & (alive != 0)
+    return live
+
+
+def _first_live_tile(lo, hi, alive, *, skip_retired: bool, first_block,
+                     slice_start, slice_end, block_size: int, block_t: int,
+                     tiles_per_block: int, n_tiles: int):
+    """(1,) int32: the first tile that any step :func:`_step_live` admits
+    can read — the tile of the earliest row, within the slice's rows
+    ``[slice_start, slice_end)``, of any lane's window ``[lo, hi)`` (alive
+    lanes only, with ``skip_retired``); the last tile where no window
+    holds a row. The tile index maps clamp to it, so the skipped steps
+    before it fetch nothing new."""
+    lo = jnp.maximum(lo, slice_start)
+    has_rows = lo < jnp.minimum(hi, slice_end)
+    if skip_retired:
+        has_rows = has_rows & (alive != 0)
+    row = jnp.min(jnp.where(has_rows, lo, slice_end))
+    # rows are non-negative: truncating division, with no sign fix-up
+    tile = (jax.lax.div(row, block_size) - first_block) * tiles_per_block \
+        + jax.lax.div(jax.lax.rem(row, block_size), block_t)
+    return jnp.clip(tile, 0, n_tiles - 1).astype(jnp.int32).reshape(1)
+
+
+def _clamp_tile(tile, first_ref, n_tiles: int):
+    """The tile a grid step reads: ``min(max(tile, first), last)``."""
+    return jnp.minimum(jnp.maximum(tile, first_ref[0]), n_tiles - 1)
 
 
 def _tile_partials(v, mult, reserve, act, weight, g, *, num_blocks: int,
@@ -140,13 +196,14 @@ def _predict_lane(sums, b, s_hat, act, n_hat, *, n_events: int):
     return c_next, no_cap.astype(jnp.int32), n_next
 
 
-def _round_kernel(v_ref, mult_ref, act_ref, b_ref, s_hat_ref,
+def _round_kernel(first_ref, v_ref, mult_ref, act_ref, b_ref, s_hat_ref,
                   reserve_ref, n_hat_ref, alive_ref,
                   rate_parts_ref, block_parts_ref, c_next_ref, no_cap_ref,
                   n_next_ref,
                   *, second_price: bool, skip_retired: bool, n_events: int,
                   block_size: int, num_blocks: int, block_t: int,
                   tiles_per_block: int):
+    # first_ref, the scalar-prefetched clamp, is read by the index map only
     phase = pl.program_id(0)
     tile = pl.program_id(1)
     scn = pl.program_id(2)
@@ -175,14 +232,19 @@ def _round_kernel(v_ref, mult_ref, act_ref, b_ref, s_hat_ref,
 
         jax.lax.fori_loop(0, n_lanes, lane, 0)
 
-    def tile_work():
+    # phase 0: remaining events [n_hat, N); phase 1: the predicted block
+    # [n_hat, n_next) — same weight, upper-clipped. Both windows end at or
+    # before N; rows past their block's end are padding.
+    hi = jnp.where(phase == 0, jnp.int32(n_events), n_next_ref[scn])
+
+    @pl.when(_step_live(tile, n_hat_ref[scn], hi, alive_ref[scn],
+                        skip_retired=skip_retired, first_block=0,
+                        block_size=block_size, block_t=block_t,
+                        tiles_per_block=tiles_per_block))
+    def _tile_work():
         g, rel = _tile_rows(tile, tiles_per_block=tiles_per_block,
                             block_t=block_t)
         gidx = g * block_size + rel
-        # phase 0: remaining events [n_hat, N); phase 1: the predicted
-        # block [n_hat, n_next) — same weight, upper-clipped. Both windows
-        # end at or before N; rows past their block's end are padding.
-        hi = jnp.where(phase == 0, jnp.int32(n_events), n_next_ref[scn])
         weight = ((rel < block_size) & (gidx >= n_hat_ref[scn])
                   & (gidx < hi)).astype(jnp.float32)
         part = _tile_partials(
@@ -197,13 +259,6 @@ def _round_kernel(v_ref, mult_ref, act_ref, b_ref, s_hat_ref,
         @pl.when(phase == 1)
         def _():
             block_parts_ref[scn] += part
-
-    if skip_retired:
-        @pl.when(alive_ref[scn] != 0)
-        def _():
-            tile_work()
-    else:
-        tile_work()
 
 
 def round_fused_pallas(
@@ -237,24 +292,33 @@ def round_fused_pallas(
     assert rows % block_t == 0, (rows, block_t)
     g = num_reduce_blocks
 
-    grid = (2, rows // block_t, s)
+    n_tiles = rows // block_t
+    first = _first_live_tile(
+        n_hat, jnp.int32(n_events), lane_alive, skip_retired=skip_retired,
+        first_block=0, slice_start=0, slice_end=n_events,
+        block_size=block_size, block_t=block_t,
+        tiles_per_block=tiles_per_block, n_tiles=n_tiles)
     kernel = functools.partial(
         _round_kernel, second_price=second_price, skip_retired=skip_retired,
         n_events=n_events, block_size=block_size, num_blocks=g,
         block_t=block_t, tiles_per_block=tiles_per_block)
 
-    full_sc = pl.BlockSpec((s, c), lambda p, i, j: (0, 0))
-    parts = pl.BlockSpec((s, g, c), lambda p, i, j: (0, 0, 0))
+    full_sc = pl.BlockSpec((s, c), lambda p, i, j, f: (0, 0))
+    parts = pl.BlockSpec((s, g, c), lambda p, i, j, f: (0, 0, 0))
     lane_i32 = jax.ShapeDtypeStruct((s,), jnp.int32)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_t, c), lambda p, i, j: (i, 0)),   # tiles
-            full_sc, full_sc, full_sc, full_sc,   # mult, active, b, s_hat
-            SMEM, SMEM, SMEM,                     # reserves, n_hat, alive
-        ],
-        out_specs=[parts, parts, SMEM, SMEM, SMEM],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                # first
+            grid=(2, n_tiles, s),
+            in_specs=[
+                pl.BlockSpec((block_t, c), lambda p, i, j, f: (
+                    _clamp_tile(i, f, n_tiles), 0)),        # tiles
+                full_sc, full_sc, full_sc, full_sc,   # mult, active, b, s_hat
+                SMEM, SMEM, SMEM,                     # reserves, n_hat, alive
+            ],
+            out_specs=[parts, parts, SMEM, SMEM, SMEM],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((s, g, c), jnp.float32),
             jax.ShapeDtypeStruct((s, g, c), jnp.float32),
@@ -262,16 +326,17 @@ def round_fused_pallas(
         ],
         interpret=interpret,
         name="round_fused",
-    )(tiles, multipliers, active, budgets, s_hat, reserves, n_hat,
+    )(first, tiles, multipliers, active, budgets, s_hat, reserves, n_hat,
       lane_alive)
 
 
-def _partials_kernel(v_ref, mult_ref, act_ref, reserve_ref,
+def _partials_kernel(first_ref, v_ref, mult_ref, act_ref, reserve_ref,
                      lo_ref, hi_ref, alive_ref, place_ref,
                      parts_ref,
                      *, second_price: bool, skip_retired: bool,
                      block_size: int, num_blocks: int, block_t: int,
                      tiles_per_block: int):
+    # first_ref, the scalar-prefetched clamp, is read by the index map only
     tile = pl.program_id(0)
     scn = pl.program_id(1)
 
@@ -279,11 +344,16 @@ def _partials_kernel(v_ref, mult_ref, act_ref, reserve_ref,
     def _init():
         parts_ref[...] = jnp.zeros_like(parts_ref)
 
-    def tile_work():
+    # place_ref: (first canonical block of the slice, global index of the
+    # slice's first row, of its last row + 1)
+    @pl.when(_step_live(tile, jnp.maximum(lo_ref[scn], place_ref[1]),
+                        jnp.minimum(hi_ref[scn], place_ref[2]),
+                        alive_ref[scn], skip_retired=skip_retired,
+                        first_block=place_ref[0], block_size=block_size,
+                        block_t=block_t, tiles_per_block=tiles_per_block))
+    def _tile_work():
         b, rel = _tile_rows(tile, tiles_per_block=tiles_per_block,
                             block_t=block_t)
-        # place_ref: (first canonical block of the slice, global index of
-        # the slice's first row, of its last row + 1)
         g = place_ref[0] + b
         gidx = g * block_size + rel
         weight = ((rel < block_size) & (gidx >= place_ref[1])
@@ -293,13 +363,6 @@ def _partials_kernel(v_ref, mult_ref, act_ref, reserve_ref,
             v_ref[...].astype(jnp.float32), mult_ref[pl.ds(scn, 1), :],
             reserve_ref[scn], act_ref[pl.ds(scn, 1), :] != 0, weight, g,
             num_blocks=num_blocks, second_price=second_price)
-
-    if skip_retired:
-        @pl.when(alive_ref[scn] != 0)
-        def _():
-            tile_work()
-    else:
-        tile_work()
 
 
 def sweep_partials_pallas(
@@ -329,22 +392,30 @@ def sweep_partials_pallas(
     s = multipliers.shape[0]
     assert rows % block_t == 0, (rows, block_t)
     g = num_reduce_blocks
-    grid = (rows // block_t, s)
+    n_tiles = rows // block_t
+    first = _first_live_tile(
+        lo, hi, lane_alive, skip_retired=skip_retired, first_block=place[0],
+        slice_start=place[1], slice_end=place[2], block_size=block_size,
+        block_t=block_t, tiles_per_block=tiles_per_block, n_tiles=n_tiles)
     kernel = functools.partial(
         _partials_kernel, second_price=second_price,
         skip_retired=skip_retired, block_size=block_size, num_blocks=g,
         block_t=block_t, tiles_per_block=tiles_per_block)
-    full_sc = pl.BlockSpec((s, c), lambda i, j: (0, 0))
+    full_sc = pl.BlockSpec((s, c), lambda i, j, f: (0, 0))
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_t, c), lambda i, j: (i, 0)),
-            full_sc, full_sc,                     # multipliers, active
-            SMEM, SMEM, SMEM, SMEM, SMEM,         # reserves, lo, hi, alive,
-        ],                                        # place
-        out_specs=pl.BlockSpec((s, g, c), lambda i, j: (0, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                # first
+            grid=(n_tiles, s),
+            in_specs=[
+                pl.BlockSpec((block_t, c), lambda i, j, f: (
+                    _clamp_tile(i, f, n_tiles), 0)),
+                full_sc, full_sc,                 # multipliers, active
+                SMEM, SMEM, SMEM, SMEM, SMEM,     # reserves, lo, hi, alive,
+            ],                                    # place
+            out_specs=pl.BlockSpec((s, g, c), lambda i, j, f: (0, 0, 0)),
+        ),
         out_shape=jax.ShapeDtypeStruct((s, g, c), jnp.float32),
         interpret=interpret,
         name="sweep_partials",
-    )(tiles, multipliers, active, reserves, lo, hi, lane_alive, place)
+    )(first, tiles, multipliers, active, reserves, lo, hi, lane_alive, place)

@@ -1,4 +1,6 @@
 """Per-kernel shape/dtype sweeps vs pure-jnp oracles (interpret=True)."""
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,6 +15,10 @@ from repro.kernels.auction_resolve import (auction_resolve,
                                            sweep_resolve, sweep_resolve_ref)
 from repro.kernels.capped_scan import capped_scan, capped_scan_ref
 from repro.kernels.flash_attention import flash_attention, flash_attention_ref
+
+# the module, which the package's ``round_fused`` (the jitted entry) hides
+round_fused_kernels = importlib.import_module(
+    "repro.kernels.auction_resolve.round_fused")
 
 
 @pytest.mark.parametrize("n,c,d,sp,per_event", [
@@ -233,6 +239,143 @@ def test_round_fused_tiles_is_the_values_entry(s, n, c, sp, blk):
                        interpret=True)
     for a, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(w))
+
+
+@pytest.fixture
+def poison_zero_weight_steps():
+    """Calling it makes every fused-kernel grid step that does tile work
+    with every weight 0 — a tile holding no row of its lane's window —
+    add NaN to the partials. (A log value cannot show such a step: a
+    weight of 0 multiplies the spends, and XLA folds that product into a
+    select, so even an infinite price adds 0.)"""
+    original = round_fused_kernels._tile_partials
+
+    def poisoned(v, mult, reserve, act, weight, g, **kw):
+        part = original(v, mult, reserve, act, weight, g, **kw)
+        return jnp.where(jnp.any(weight != 0), part, jnp.nan)
+
+    def poison():
+        round_fused_kernels._tile_partials = poisoned
+        jax.clear_caches()
+
+    yield poison
+    round_fused_kernels._tile_partials = original
+    jax.clear_caches()
+
+
+def _budgets_capping_at(v, mult, act, res, b, s_hat, n_hat, lane, row, *,
+                        block_size):
+    """Budgets under which ``lane``'s next cap-out is predicted at ``row``:
+    its top-spending campaign's budget sits half an event past ``row`` at
+    the lane's remaining rate, every other budget is out of reach."""
+    n = v.shape[0]
+    rate_parts = round_fused_ref(v, mult, act, res, b, s_hat, n_hat,
+                                 block_size=block_size, reduce_blocks=32)[0]
+    sums = np.asarray(rate_parts[lane]).sum(axis=0)
+    top = int(np.argmax(sums))
+    nh = int(n_hat[lane])
+    b = np.full(b.shape, 1e9, np.float32)
+    b[lane, top] = float(s_hat[lane, top]) \
+        + (row - nh + 0.5) * sums[top] / (n - nh)
+    return jnp.asarray(b)
+
+
+@pytest.mark.parametrize("n,blk,n_hat,alive,n_next_at", [
+    (3200, 64, [364, 500, 1500], [1, 1, 1], None),    # on a tile boundary
+    (3200, 64, [330, 1250, 0], [1, 1, 0], None),      # mid-tile; a retired
+    #                                                   lane at row 0
+    (3200, 64, [395, 450, 2000], [1, 1, 1], None),    # in a block's last
+    #                                                   tile, before padding
+    (3200, 64, [400, 617, 3000], [1, 1, 1], None),    # a canonical block
+    (3200, 64, [3200, 3200, 3200], [1, 1, 1], None),  # empty windows
+    (3200, 64, [364, 500, 1500], [1, 1, 1], 564),     # phase 1's n_next at
+    #                                                   a tile's first row
+    (300, 64, [37, 150, 299], [1, 1, 1], None),       # blocks under a tile
+], ids=["tile_boundary", "mid_tile", "block_last_tile", "block_boundary",
+        "empty", "n_next_at_tile", "small_blocks"])
+def test_round_fused_skips_steps_outside_every_window(
+        poison_zero_weight_steps, n, blk, n_hat, alive, n_next_at):
+    """The fused round does tile work only where the tile holds a row of
+    its alive lane's window: with every other step poisoned, the outputs
+    are finite and bitwise the unpoisoned kernel's, which are the
+    oracle's."""
+    s, c = len(n_hat), 40
+    v, mult, act, res, b, s_hat, _ = _fused_inputs(s, n, c, seed=9)
+    n_hat = jnp.asarray(n_hat, jnp.int32)
+    alive = jnp.asarray(alive, bool)
+    block_size = -(-n // 32)
+    if n_next_at is not None:
+        b = _budgets_capping_at(v, mult, act, res, b, s_hat, n_hat, 0,
+                                n_next_at, block_size=block_size)
+
+    def run():
+        return [np.asarray(x) for x in round_fused(
+            v, mult, act, res, b, s_hat, n_hat, alive, reduce_blocks=32,
+            block_t=blk, interpret=True)]
+
+    clean = run()
+    poison_zero_weight_steps()
+    for got, want in zip(run(), clean):
+        assert np.isfinite(got.astype(np.float64)).all()
+        np.testing.assert_array_equal(got, want)
+    if n_next_at is not None:
+        assert int(clean[4][0]) == n_next_at
+    live = np.asarray(alive)
+    ref = round_fused_ref(v, mult, act, res, b, s_hat, n_hat,
+                          block_size=block_size, reduce_blocks=32)
+    for k in (0, 1):
+        np.testing.assert_allclose(clean[k][live], np.asarray(ref[k])[live],
+                                   rtol=1e-5, atol=1e-5)
+    for k in (2, 3, 4):
+        assert np.array_equal(clean[k][live], np.asarray(ref[k])[live])
+
+
+@pytest.mark.parametrize("offset,local_n,blk,lo,hi,alive", [
+    (0, 2048, 16, [528, 700, 1300], [1500, 1200, 1700], [1, 1, 1]),
+    #                                  the whole log; on a tile boundary
+    (512, 512, 16, [600, 650, 0], [900, 1000, 2048], [1, 1, 0]),
+    #                                  a shard; mid-tile; a retired lane
+    (1536, 512, 64, [1600, 1700, 1800], [2048] * 3, [1, 1, 1]),
+    #                                  a shard; on a canonical block
+    (512, 512, 16, [1024, 1100, 2000], [2048] * 3, [1, 1, 1]),
+    #                                  a shard wholly behind lo
+    (100, 300, 16, [150, 170, 390], [300, 395, 400], [1, 1, 1]),
+    #                                  a resumable fold: starts mid-block
+    (0, 2048, 16, [1000] * 3, [1000] * 3, [1, 1, 1]),
+    #                                  empty windows
+], ids=["tile_boundary", "mid_tile", "block_boundary", "shard_behind_lo",
+        "mid_block_fold", "empty"])
+def test_sweep_partials_skips_steps_outside_every_window(
+        poison_zero_weight_steps, offset, local_n, blk, lo, hi, alive):
+    """The weighted partials pass does tile work only where the tile holds
+    a row of its alive lane's window within the slice: with every other
+    step poisoned, the partials are finite and bitwise the unpoisoned
+    kernel's, which are the oracle's."""
+    s, n_global, c = len(lo), 2048, 20
+    v, mult, act, res, _, _, _ = _fused_inputs(s, n_global, c)
+    v_local = v[offset:offset + local_n]
+    block_size = -(-n_global // 32)
+    lo, hi = jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32)
+    alive = jnp.asarray(alive, bool)
+
+    def run():
+        return np.asarray(sweep_partials(
+            v_local, mult, act, res, lo, hi, alive, jnp.int32(offset),
+            n_events_global=n_global, reduce_blocks=32,
+            offset_in_block=offset % block_size, block_t=blk,
+            interpret=True))
+
+    clean = run()
+    poison_zero_weight_steps()
+    got = run()
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, clean)
+    live = np.asarray(alive)
+    ref = fused_partials_ref(v_local, mult, act, res, lo, hi,
+                             block_size=block_size, reduce_blocks=32,
+                             index_offset=offset)
+    np.testing.assert_allclose(clean[live], np.asarray(ref)[live],
+                               rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("offset,local_n,blk", [

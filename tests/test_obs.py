@@ -164,6 +164,23 @@ def test_sweep_span_says_where_the_log_is_laid_out(env, traced, chunks,
     assert sweep.attrs["layout"] == layout
 
 
+@pytest.mark.parametrize("resolve,interpret,tile_skip", [
+    ("jnp", None, None), ("fused", True, "window")])
+def test_sweep_span_says_whether_the_kernels_skip_tiles(env, traced, resolve,
+                                                        interpret, tile_skip):
+    """``executor.sweep`` carries ``tile_skip="window"`` where the plan runs
+    the fused kernels, which skip every grid step whose tile holds no row
+    of its lane's window; no ``tile_skip`` where no kernel runs."""
+    from repro.core.executor import execute_sweep, plan_for_driver
+    engine = CounterfactualEngine(env.values, env.budgets)
+    grid = engine.grid(bid_scales=(1.0, 1.25))
+    execute_sweep(env.values, grid.budgets, grid.rules,
+                  plan_for_driver("batched", resolve=resolve,
+                                  interpret=interpret))
+    sweep, = _by_name(obs.records(), "executor.sweep")
+    assert sweep.attrs.get("tile_skip") == tile_skip
+
+
 @pytest.mark.parametrize("driver", ["batched", "sharded"])
 def test_sweep_span_says_how_the_log_is_sharded(env, traced, driver):
     """Under ``placement="sharded"`` ``executor.sweep`` also carries
