@@ -11,7 +11,6 @@ import numpy as np
 from benchmarks.common import emit, time_call
 from repro.kernels.auction_resolve import auction_resolve, auction_resolve_ref
 from repro.kernels.capped_scan import capped_scan, capped_scan_ref
-from repro.kernels.flash_attention import flash_attention, flash_attention_ref
 
 
 def main() -> None:
@@ -44,20 +43,6 @@ def main() -> None:
          f"N={n2};C={c};hbm_bytes={n2 * c * 4:.2e}")
     _, us_k2 = time_call(lambda: capped_scan(v, budgets), repeats=1)
     emit("kernel_capped_scan_pallas_interp", us_k2, "")
-
-    # flash attention: B=1 S=1024 H=4 dh=64
-    b, s, h, dh = 1, 1024, 4, 64
-    q = jax.random.normal(k1, (b, s, h, dh), jnp.bfloat16)
-    kk = jax.random.normal(k2, (b, s, h, dh), jnp.bfloat16)
-    _, us_ref3 = time_call(
-        lambda: flash_attention_ref(
-            q.transpose(0, 2, 1, 3).reshape(b * h, s, dh),
-            kk.transpose(0, 2, 1, 3).reshape(b * h, s, dh),
-            kk.transpose(0, 2, 1, 3).reshape(b * h, s, dh)), repeats=2)
-    emit("kernel_flash_attention_ref", us_ref3,
-         f"S={s};flops={4 * b * h * s * s * dh:.2e}")
-    _, us_k3 = time_call(lambda: flash_attention(q, kk, kk), repeats=1)
-    emit("kernel_flash_attention_pallas_interp", us_k3, "")
 
 
 if __name__ == "__main__":

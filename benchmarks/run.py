@@ -1,5 +1,5 @@
-"""Benchmark harness — one module per paper table/figure plus the roofline
-table from the dry-run artifacts. Prints ``name,us_per_call,derived`` CSV.
+"""Benchmark harness — one module per paper table/figure plus the scaling
+and kernel tables. Prints ``name,us_per_call,derived`` CSV.
 """
 from __future__ import annotations
 
@@ -10,13 +10,13 @@ import traceback
 def main() -> None:
     from benchmarks import (fig1_naive_sampling, fig2_seq_vs_parallel,
                             fig3_vi_convergence, fig4_sort2aggregate,
-                            fig56_yahoo_day2, kernels_bench, roofline_table,
-                            scaling, sweep_scaling)
+                            fig56_yahoo_day2, kernels_bench, scaling,
+                            sweep_scaling)
     print("name,us_per_call,derived")
     failed = []
     for mod in (fig1_naive_sampling, fig2_seq_vs_parallel,
                 fig3_vi_convergence, fig4_sort2aggregate, fig56_yahoo_day2,
-                scaling, sweep_scaling, kernels_bench, roofline_table):
+                scaling, sweep_scaling, kernels_bench):
         try:
             mod.main()
         except Exception as e:   # run the rest, then fail the harness

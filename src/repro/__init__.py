@@ -1,3 +1,4 @@
 """repro: counterfactual simulation for large-scale systems with burnout
-variables (Heymann, CS.DC 2025) — multi-pod JAX framework."""
+variables (Heymann, CS.DC 2025) — scenario sweeps and an always-on what-if
+service in JAX, for TPUs."""
 __version__ = "1.0.0"

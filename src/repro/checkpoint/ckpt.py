@@ -1,18 +1,16 @@
-"""Sharded checkpointing with async writes and elastic (re-sharded) restore.
+"""Checkpointing of pytrees, with sync and async writes.
 
 Layout: one directory per step containing
 
-* ``manifest.json`` — step, tree structure, per-leaf shape/dtype, mesh info;
-* ``arrays.npz`` (or per-leaf ``.npy`` over a size threshold) — *logical*
-  (unsharded) array values.
+* ``manifest.json`` — step, per-leaf shape/dtype and caller extras;
+* ``arrays.npz`` — *logical* (unsharded) array values.
 
 Saving gathers each leaf to host (addressable shards -> logical array) —
-correct on any mesh. Restoring places leaves with whatever sharding the
-*current* mesh dictates, so a checkpoint written on (16,16) restores onto
-(8,16) or (2,16,16) unchanged — this is the elastic-rescale path
-(``repro.fault.elastic``). Writes happen on a background thread
-(:class:`AsyncCheckpointer`): training continues while the previous step
-serialises, and ``wait()`` gives a barrier for tests/shutdown.
+correct on any mesh. :class:`repro.serve.CounterfactualService` persists its
+log, carries and cache through :func:`save_checkpoint` /
+:func:`restore_checkpoint`. :class:`AsyncCheckpointer` writes on a
+background thread (the caller goes on while the previous step serialises),
+and ``wait()`` gives a barrier for tests/shutdown.
 """
 from __future__ import annotations
 
@@ -22,7 +20,7 @@ import queue
 import threading
 import time
 from pathlib import Path
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -87,11 +85,9 @@ def latest_step(path: str | Path) -> Optional[int]:
 
 
 def restore_checkpoint(path: str | Path, like: Tree,
-                       step: Optional[int] = None,
-                       shardings: Optional[Tree] = None) -> Tuple[Tree, Dict]:
-    """Restore into the structure of ``like`` (values ignored). If
-    ``shardings`` (a matching tree of NamedSharding) is given, leaves are
-    placed sharded — on *any* mesh, enabling elastic restore."""
+                       step: Optional[int] = None) -> Tuple[Tree, Dict]:
+    """Restore into the structure of ``like`` (values ignored), leaves on
+    the default device."""
     root = Path(path)
     if step is None:
         step = latest_step(root)
@@ -102,18 +98,11 @@ def restore_checkpoint(path: str | Path, like: Tree,
     data = np.load(ckpt_dir / "arrays.npz")
 
     flat, treedef = _flatten(like)
-    shard_flat = None
-    if shardings is not None:
-        shard_flat = [s for _, s in _flatten(shardings)[0]]
     leaves = []
-    for i, (key, leaf) in enumerate(flat):
+    for key, _ in flat:
         if key not in data:
             raise KeyError(f"checkpoint missing leaf {key}")
-        arr = data[key]
-        if shard_flat is not None:
-            leaves.append(jax.device_put(arr, shard_flat[i]))
-        else:
-            leaves.append(jnp.asarray(arr))
+        leaves.append(jnp.asarray(data[key]))
     tree = jax.tree_util.tree_unflatten(treedef, leaves)
     return tree, manifest
 

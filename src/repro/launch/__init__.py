@@ -1,6 +1,5 @@
-"""Launch layer. NOTE: repro.launch.dryrun sets XLA_FLAGS at import — import
-it only in dedicated processes (the dry-run/hillclimb CLIs)."""
-from repro.launch.mesh import (make_production_mesh, make_mesh, data_axes,
-                               SweepMeshSpec)
+"""Launch layer: sweep meshes, the compile cache, and the cost models the
+plan tuner reads."""
+from repro.launch.mesh import SweepMeshSpec
 
-__all__ = ["make_production_mesh", "make_mesh", "data_axes", "SweepMeshSpec"]
+__all__ = ["SweepMeshSpec"]

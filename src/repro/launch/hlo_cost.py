@@ -2,8 +2,9 @@
 
 XLA's built-in ``cost_analysis()`` counts while-loop bodies ONCE (verified:
 a 10-iteration scan of a matmul reports 1 matmul of FLOPs), which silently
-underreports every scanned-layers model. Unrolling for analysis is exact but
-compiles orders of magnitude slower on this 1-core container. This module
+underreports every scanned program (the sweep's round loop and chunk scans).
+Unrolling for analysis is exact but compiles orders of magnitude slower. This
+module
 instead walks the *optimized HLO text* structurally:
 
 * computations are parsed into instruction lists (result type, op, operands,
@@ -59,8 +60,8 @@ _TRANSCENDENTAL = {"exponential", "log", "tanh", "rsqrt", "sqrt", "power",
                    "log-plus-one", "atan2", "cbrt", "erf"}
 
 
-# shared with launch/roofline.py via launch/hlo_text.py; the local names
-# stay because tests and this module's walker address them directly
+# from launch/hlo_text.py; the local names stay because tests and this
+# module's walker address them directly
 _shape_list = hlo_text.shape_list
 _type_bytes = hlo_text.type_bytes
 

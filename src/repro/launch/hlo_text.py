@@ -1,11 +1,6 @@
-"""Shared HLO-text parsing primitives for the launch cost models.
-
-``launch/roofline.py`` (line-oriented collective scan) and
-``launch/hlo_cost.py`` (structural trip-count-aware walker) both parse
-optimized HLO text. The dtype-size table, the shape/replica-group regexes
-and the ring-formula collective wire-byte model used to be copy-pasted
-between them; they live here once so the tuner's cost model, the roofline
-deriver and the structural walker cannot drift apart.
+"""HLO-text parsing primitives for ``launch/hlo_cost.py``'s structural,
+trip-count-aware walker: the dtype-size table, the shape/replica-group
+regexes and the ring-formula collective wire-byte model.
 
 Ring formulas (per-device wire traffic for a group of size ``n``):
 
@@ -34,10 +29,6 @@ GROUPS_RE = re.compile(r"replica_groups=\{?\{([\d,\s]+)\}")
 # e.g. replica_groups=[32,16]<=[16,32]T(1,0) — iota form: groups x size
 GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 
-COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
-               "collective-permute")
-
-
 def shape_list(type_str: str) -> List[Tuple[str, Tuple[int, ...]]]:
     """All (dtype, shape) pairs in an HLO type string (tuples flatten)."""
     out = []
@@ -60,8 +51,7 @@ def type_bytes(type_str: str) -> int:
 def group_size(attrs: str, default: int = 2) -> int:
     """Replica-group size from an instruction's attribute text.
 
-    ``default`` is the conservative fallback when groups are implicit
-    (roofline's line scan uses 2; the structural walker clamps to >= 1).
+    ``default`` is the conservative fallback when groups are implicit.
     """
     m = GROUPS_IOTA_RE.search(attrs)
     if m:

@@ -1,17 +1,11 @@
-"""Production mesh construction + sweep mesh specs.
-
-A function (not a module-level constant) so importing this module never
-touches jax device state. Single pod: (16, 16) = 256 chips, axes
-("data", "model"). Multi-pod: (2, 16, 16) = 512 chips with a leading "pod"
-axis that is pure data-parallel — the only cross-pod collective in any of our
-programs is the per-step gradient/residual all-reduce (which
-repro.comm.compression can compress), so scaling beyond 2 pods = growing this
-axis.
+"""Sweep mesh specs.
 
 :class:`SweepMeshSpec` names how a scenario sweep maps onto a mesh: which
 axes shard the event log and which (optional) axis shards the scenario grid —
-the contract consumed by :func:`repro.core.sharded.sweep_sharded`. Axis
-conventions are documented in docs/SCALING.md.
+the contract consumed by :func:`repro.core.sharded.sweep_sharded`. Building
+one is a function call, never an import side effect, so importing this
+module never touches jax device state. Axis conventions are documented in
+docs/SCALING.md.
 """
 from __future__ import annotations
 
@@ -27,22 +21,6 @@ def _make_mesh(shape, axes):
     one place this repo spells ``AxisType``)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
                          axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh (tests, elastic rescale)."""
-    return _make_mesh(shape, axes)
-
-
-def data_axes(mesh) -> tuple:
-    """The event/batch axes of a mesh (everything except 'model')."""
-    return tuple(a for a in mesh.axis_names if a != "model")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -22,8 +22,7 @@ coordinate steps — reproducible trajectories, no RNG):
   O(width/δ).
 * :func:`coordinate_hillclimb` — pattern search over the axes: the ±step
   neighborhood is ONE scenario batch per iteration; steps halve when no
-  neighbor improves (seeded from the hypothesis→measure→record loop of
-  ``repro.launch.hillclimb``).
+  neighbor improves.
 
 Constraints (e.g. :class:`CapRateCeiling`, the delta-table ``num_capped``
 rate) enter as feasibility margins: feasible candidates are ranked by

@@ -148,11 +148,8 @@ def coordinate_hillclimb(evaluate: Evaluate, space: SearchSpace,
     incumbent as ONE scenario batch per iteration; move to the best
     improving neighbor, else halve every step. Stops when all steps are
     within ``xatol`` of the axis widths (``converged``) or the next
-    neighborhood no longer fits the ledger.
-
-    The hypothesis → measure → record loop follows the perf hillclimb
-    driver (``repro.launch.hillclimb``), with the measurement a batched
-    counterfactual sweep instead of a compile.
+    neighborhood no longer fits the ledger. Each measurement is a batched
+    counterfactual sweep.
     """
     x = space.clip(dict(init) if init else space.center())
     widths = space.widths()

@@ -1,9 +1,4 @@
-from repro.serve.engine import (ServeEngine, RequestBatch, ServePlan,
-                                estimate_exit_steps, plan_compactions,
-                                wasted_slot_steps)
 from repro.serve.counterfactual import (CounterfactualService, ServiceAnswer,
                                         Ticket)
 
-__all__ = ["ServeEngine", "RequestBatch", "ServePlan", "estimate_exit_steps",
-           "plan_compactions", "wasted_slot_steps",
-           "CounterfactualService", "ServiceAnswer", "Ticket"]
+__all__ = ["CounterfactualService", "ServiceAnswer", "Ticket"]

@@ -1,4 +1,3 @@
-import os
 import sys
 from pathlib import Path
 
@@ -6,13 +5,5 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 # NOTE: no XLA_FLAGS here on purpose — smoke tests and benches must see the
-# real single CPU device; only launch/dryrun.py forces 512 host devices.
-
-import jax  # noqa: E402
-
-import pytest  # noqa: E402
-
-
-@pytest.fixture(scope="session")
-def rng_key():
-    return jax.random.PRNGKey(0)
+# real single CPU device; multi-device tests set it in a subprocess or a CI
+# step of their own.

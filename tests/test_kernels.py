@@ -14,7 +14,6 @@ from repro.kernels.auction_resolve import (auction_resolve,
                                            sweep_partials_tiles,
                                            sweep_resolve, sweep_resolve_ref)
 from repro.kernels.capped_scan import capped_scan, capped_scan_ref
-from repro.kernels.flash_attention import flash_attention, flash_attention_ref
 
 # the module, which the package's ``round_fused`` (the jitted entry) hides
 round_fused_kernels = importlib.import_module(
@@ -441,31 +440,3 @@ def test_capped_scan_equals_core_oracle():
     np.testing.assert_allclose(np.asarray(s), np.asarray(ref.final_spend),
                                rtol=1e-4)
     assert np.array_equal(np.asarray(cap), np.asarray(ref.cap_times))
-
-
-@pytest.mark.parametrize("b,s,h,kv,dh,causal,window,dtype", [
-    (2, 256, 4, 2, 64, True, None, jnp.float32),
-    (1, 512, 2, 2, 64, True, 128, jnp.float32),
-    (2, 128, 4, 1, 32, False, None, jnp.bfloat16),
-    (1, 384, 3, 3, 128, True, None, jnp.float32),
-    (1, 64, 2, 2, 16, True, 16, jnp.float32),
-])
-def test_flash_attention_matches_ref(b, s, h, kv, dh, causal, window, dtype):
-    key = jax.random.PRNGKey(s + h)
-    k1, k2, k3 = jax.random.split(key, 3)
-    q = jax.random.normal(k1, (b, s, h, dh), dtype)
-    k = jax.random.normal(k2, (b, s, kv, dh), dtype)
-    v = jax.random.normal(k3, (b, s, kv, dh), dtype)
-    o1 = flash_attention(q, k, v, causal=causal, window=window,
-                         block_q=128, block_k=128)
-    kk = jnp.repeat(k, h // kv, 2)
-    vv = jnp.repeat(v, h // kv, 2)
-    o2 = flash_attention_ref(
-        q.transpose(0, 2, 1, 3).reshape(b * h, s, dh),
-        kk.transpose(0, 2, 1, 3).reshape(b * h, s, dh),
-        vv.transpose(0, 2, 1, 3).reshape(b * h, s, dh),
-        causal=causal, window=window,
-    ).reshape(b, h, s, dh).transpose(0, 2, 1, 3)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(o1, np.float32),
-                               np.asarray(o2, np.float32), rtol=tol, atol=tol)

@@ -13,8 +13,6 @@ import numpy as np
 
 from repro.core import (AuctionRule, Segments, auction, capped_sum,
                         sequential_replay, spend_sums)
-from repro.comm import (compress_with_feedback, dequantize_int8,
-                        quantize_int8)
 
 settings.register_profile("ci", deadline=None, max_examples=25,
                           derandomize=True)
@@ -74,30 +72,6 @@ def test_capped_sum_bounds(n, budget):
     assert out <= float(xs.sum()) + 1e-6
     assert out == min(budget, float(xs.sum())) or abs(
         out - min(budget, float(xs.sum()))) < 1e-4
-
-
-@given(hnp.arrays(np.float32, st.integers(1, 2000),
-                  elements=st.floats(-100.0, 100.0, width=32)))
-def test_int8_quantization_error_bound(x):
-    """Per-block error <= max|block| * (1/254 + bf16 scale rounding)."""
-    q, scale = quantize_int8(jnp.asarray(x))
-    recon = np.asarray(dequantize_int8(q, scale, x.shape[0]))
-    blocks = np.pad(x, (0, (-len(x)) % 256)).reshape(-1, 256)
-    # 1/254 from the int8 grid + 2^-8 relative from storing scales in bf16
-    per_block = np.abs(blocks).max(1) * (1 / 254.0 + 2.0 ** -8) + 1e-6
-    bound = np.repeat(per_block, 256)[: len(x)]
-    assert (np.abs(recon - x) <= bound + 1e-5).all()
-
-
-@given(hnp.arrays(np.float32, st.integers(4, 512),
-                  elements=st.floats(-10.0, 10.0, width=32)))
-def test_error_feedback_preserves_mass(x):
-    """grad + error == recon + new_error exactly (feedback conservation)."""
-    err0 = jnp.zeros((x.shape[0],), jnp.float32)
-    q, scale, err1 = compress_with_feedback(jnp.asarray(x), err0)
-    recon = np.asarray(dequantize_int8(q, scale, x.shape[0]))
-    np.testing.assert_allclose(recon + np.asarray(err1), x, rtol=1e-4,
-                               atol=1e-4)
 
 
 _SWEEP_N, _SWEEP_C = 512, 8        # canonical reduce block = 512/32 = 16

@@ -847,13 +847,6 @@ def test_misaligned_scenario_chunks_one_error_everywhere(env):
                        scenario_chunks=0)
 
 
-def test_engine_scenario_chunks_require_parallel_method(env):
-    engine = CounterfactualEngine(env.values, env.budgets)
-    grid = engine.grid(bid_scales=[1.0, 1.1])
-    with pytest.raises(ValueError, match="scenario_chunks"):
-        engine.sweep(grid, method="sort2aggregate", scenario_chunks=2)
-
-
 def test_vmem_gate_picks_fitting_scenario_chunk(env, monkeypatch):
     """Satellite regression: past the one-launch VMEM budget the executor
     now CHOOSES a fitting scenario chunk (largest divisor whose resident

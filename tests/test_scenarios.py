@@ -189,17 +189,18 @@ def _null_overlay(s, c, key):
         key=key, time_varying=True)
 
 
+@pytest.mark.parametrize("kind", ["first_price", "second_price"])
 @pytest.mark.parametrize("resolve", ["jnp", "fused"])
 @pytest.mark.parametrize("placement", ["device", "batched", "sharded"])
 @pytest.mark.parametrize("chunking", [(None, None), (64, 1)])
-def test_null_overlay_bitwise_base(placement, resolve, chunking):
+def test_null_overlay_bitwise_base(placement, resolve, chunking, kind):
     env = _env()
     epc, spc = chunking
     key = jax.random.PRNGKey(17)
     budgets = jnp.stack([env.budgets, env.budgets * 0.4])
     rules = AuctionRule(multipliers=jnp.ones((2, C), jnp.float32),
                        reserve=jnp.full((2,), 0.05, jnp.float32),
-                       kind="first_price")
+                       kind=kind)
     if placement == "device":
         # one unbatched lane; the overlay's fields are (C,) rows — this is
         # the executor's device-placement expansion path

@@ -416,15 +416,6 @@ def test_streaming_fold_deterministic_and_composable(env, base):
                                   np.asarray(carry.cap_times)[0])
 
 
-def test_duplicate_stream_label_rejected(env, base):
-    svc = CounterfactualService(env.budgets, base, events_per_chunk=_EPC)
-    svc.register("x")
-    with pytest.raises(ValueError, match="already registered"):
-        svc.register("x")
-    with pytest.raises(ValueError, match="unknown streaming scenario"):
-        svc.streaming("y")
-
-
 # ---------------------------------------------------------------------------
 # carry round-trips (satellite: SweepCarry survives transfer + pickle)
 # ---------------------------------------------------------------------------
@@ -522,22 +513,6 @@ def test_host_store_never_concatenates(env, base):
     assert stream.n_events == _N and len(stream._slabs) == 4
 
 
-def test_host_store_validation(env, base):
-    from repro.launch.mesh import SweepMeshSpec
-    with pytest.raises(ValueError, match="unknown store"):
-        CounterfactualService(env.budgets, base, store="disk")
-    with pytest.raises(ValueError, match="host-stream"):
-        CounterfactualService(env.budgets, base, store="host",
-                              placement="sharded",
-                              mesh=SweepMeshSpec.for_devices())
-    with pytest.raises(ValueError, match="scenario_chunks"):
-        CounterfactualService(env.budgets, base, store="host",
-                              scenario_chunks=2)
-    with pytest.raises(ValueError, match="REDUCE_BLOCKS"):
-        CounterfactualService(env.budgets, base, store="host",
-                              events_per_chunk=48)
-
-
 @pytest.mark.parametrize("store", ["device", "host"])
 def test_save_load_append_cycle_bitwise_uninterrupted(env, base, grid,
                                                       store):
@@ -584,23 +559,3 @@ def test_save_load_roundtrip_full_log_answer(env, base, grid, reference):
 def test_load_missing_raises(tmp_path):
     with pytest.raises(FileNotFoundError, match="no service checkpoints"):
         CounterfactualService.load(tmp_path)
-
-
-# ---------------------------------------------------------------------------
-# validation errors
-# ---------------------------------------------------------------------------
-
-def test_service_input_validation(env, base):
-    svc = CounterfactualService(env.budgets, base, events_per_chunk=_EPC)
-    with pytest.raises(ValueError, match="empty log"):
-        svc.ask().result()
-    with pytest.raises(ValueError, match=r"\(n, C=8\)"):
-        svc.append(env.values[:, :4])
-    with pytest.raises(ValueError, match="at least one event"):
-        svc.append(env.values[:0])
-    with pytest.raises(ValueError, match="scenario shape mismatch"):
-        svc.ask(budgets=env.budgets[:4])
-    with pytest.raises(ValueError, match=r"\(C,\) base design"):
-        CounterfactualService(env.budgets[None, :], base)
-    with pytest.raises(ValueError, match="max_batch"):
-        CounterfactualService(env.budgets, base, max_batch=0)

@@ -24,8 +24,8 @@ SCRIPT = textwrap.dedent("""
     env = make_synthetic_env(jax.random.PRNGKey(0), n_events=8192,
                              n_campaigns=24, emb_dim=8)
     ref = sequential_replay(env.values, env.budgets, env.rule)
-    from repro.launch.mesh import make_mesh
-    mesh = make_mesh((8,), ("data",))
+    from repro.launch.mesh import SweepMeshSpec
+    mesh = SweepMeshSpec.for_devices(8).mesh
     vals = sh.shard_events(env.values, mesh)
 
     # Algorithm 2 with mesh-sharded reductions == single-process Algorithm 2,

@@ -210,15 +210,16 @@ def _outputs(values, budgets, rules, plan):
     return ex.execute_sweep(values, budgets, rules, plan)
 
 
+@pytest.mark.parametrize("kind", ["first_price", "second_price"])
 @pytest.mark.parametrize("placement", ["device", "batched", "sharded"])
 @pytest.mark.parametrize("resolve", ["jnp", "fused"])
-def test_tuned_plan_is_bitwise_default(env, grid, placement, resolve):
+def test_tuned_plan_is_bitwise_default(env, grid, placement, resolve, kind):
     """Resolution through cache + cost model moves only bitwise-equivalence
     knobs: every output of the tuned plan equals the default plan's
-    exactly, for each placement x resolve cell (fused off TPU runs its
-    interpret-mode kernel so block_t actually reaches a grid; sharded
-    here runs the shard_map program on however many devices this process
-    has — the 4-device half is the subprocess test below)."""
+    exactly, for each placement x resolve x auction-kind cell (fused off
+    TPU runs its interpret-mode kernel so block_t actually reaches a grid;
+    sharded here runs the shard_map program on however many devices this
+    process has — the 4-device half is the subprocess test below)."""
     from repro.launch.mesh import SweepMeshSpec
     interpret = True if resolve == "fused" else None
     mesh = (SweepMeshSpec.for_devices(
@@ -228,9 +229,11 @@ def test_tuned_plan_is_bitwise_default(env, grid, placement, resolve):
         budgets, rules = grid.budgets[1], AuctionRule(
             multipliers=grid.rules.multipliers[1],
             reserve=jnp.asarray(grid.rules.reserve, jnp.float32)[1],
-            kind=grid.rules.kind)
+            kind=kind)
     else:
-        budgets, rules = grid.budgets, grid.rules
+        budgets, rules = grid.budgets, AuctionRule(
+            multipliers=grid.rules.multipliers, reserve=grid.rules.reserve,
+            kind=kind)
     base_plan = ex.SweepPlan(placement=placement, resolve=resolve,
                              interpret=interpret, mesh=mesh)
     tuned_plan = ex.SweepPlan(placement=placement, resolve=resolve,
